@@ -1,9 +1,10 @@
-"""Kernel microbenchmarks: interpret-mode correctness + jnp-path timing.
+"""Kernel checks on the CPU: interpret-mode correctness + jnp-path timing.
 
-Wall-clock here measures the *reference* path on CPU (the container has no
-TPU); the Pallas kernels themselves are validated for correctness in
-interpret mode and their perf is assessed structurally via the roofline
-(BlockSpec working sets vs VMEM, MXU-aligned tiles).
+The wall-clock column times the jnp *reference* path on the host CPU; it
+is not a kernel time.  The Pallas kernels are checked for correctness in
+interpret mode here; that they compile for a TPU v5e is checked by
+``tests/test_tpu_compile.py``, and the fused MLP runs compiled on the
+chip in ``chip_smoke.py``.  Their device times are not measured.
 """
 from __future__ import annotations
 

@@ -345,6 +345,21 @@ def jax_engine_available() -> bool:
     return pipeline_model_jax.is_available()
 
 
+def auto_engine() -> str:
+    """The pricer ``engine="auto"`` resolves to.
+
+    On the CPU backend: ``"jax"`` when the jax engine can run, else
+    ``"numpy"``.  On an accelerator backend always ``"numpy"``, without
+    importing the jax engine: its float64 guard turns on
+    ``jax_enable_x64`` for the whole process, and that process may be
+    the one serving a model on the chip.
+    """
+    import jax
+    if jax.default_backend() != "cpu":
+        return "numpy"
+    return "jax" if jax_engine_available() else "numpy"
+
+
 ENGINES = ("auto", "numpy", "jax")
 
 
@@ -384,8 +399,7 @@ class PlanRequest:
 
     ``engine`` selects the candidate pricer for engine-capable strategies
     (``supports_engine``): ``"auto"`` (default) resolves at construction
-    to ``"jax"`` when the jax engine is importable with float64 enabled,
-    else ``"numpy"``; the resolved name is what identity (``key``,
+    through ``auto_engine()``; the resolved name is what identity (``key``,
     ``cache_token``) and serialization carry, so a stored plan records
     the engine that priced it.  An explicit ``"jax"`` raises when the
     engine cannot run; any explicit non-auto engine raises for
@@ -428,8 +442,7 @@ class PlanRequest:
                     "cannot run (jax missing or float64 unavailable); "
                     "use engine='numpy' or 'auto'")
             if self.engine == "auto":
-                resolved = "jax" if jax_engine_available() else "numpy"
-                object.__setattr__(self, "engine", resolved)
+                object.__setattr__(self, "engine", auto_engine())
         elif self.engine != "auto":
             raise ValueError(
                 f"strategy {self.strategy!r} does not support engine "

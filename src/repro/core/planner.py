@@ -38,7 +38,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 from .dataflow import Dataflow, choose_dataflow
 from .depth import Segment, segment_graph
 from .plan_api import (Constraint, DEFAULT_OBJECTIVE, Objective,
-                       content_token, jax_engine_available, register_cache,
+                       auto_engine, content_token, register_cache,
                        register_strategy, unregister_cache)
 from .graph import (BranchRegion, COMPLEX_KINDS, Graph, Op, OpKind,
                     branch_regions, periodic_regions)
@@ -285,7 +285,8 @@ def resolve_engine(engine: str) -> str:
 
     ``"numpy"`` is the vectorized host engine (internal id ``"batch"``,
     the historical default); ``"jax"`` requires the jax pricer and raises
-    a clear error when it cannot run; ``"auto"`` picks jax when available.
+    a clear error when it cannot run; ``"auto"`` follows
+    ``plan_api.auto_engine()``.
     The internal ids ``"batch"``/``"reference"`` pass through for the
     benchmark harness.
     """
@@ -297,7 +298,7 @@ def resolve_engine(engine: str) -> str:
         _jax_model()                # raises with the unavailability reason
         return "jax"
     if engine == "auto":
-        return "jax" if jax_engine_available() else "batch"
+        return "jax" if auto_engine() == "jax" else "batch"
     raise ValueError(f"unknown engine {engine!r}; "
                      "one of ('auto', 'numpy', 'jax')")
 

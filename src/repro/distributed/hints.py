@@ -3,8 +3,8 @@
 GSPMD's propagation into lax.scan bodies is weak: without explicit
 constraints the per-layer activations (and especially attention scores)
 get replicated.  ``hint(x, *axes)`` applies with_sharding_constraint with
-logical axis names, resolved against whatever mesh is current at trace
-time — and is a no-op when there is no mesh (single-device smoke tests)
+logical axis names, resolved against the mesh set with ``jax.set_mesh``
+— and is a no-op when there is no mesh (single-device smoke tests)
 or when a dim is not divisible by its axis size.
 
 Logical names:  "batch" -> ("pod","data") subset present in the mesh;
@@ -16,15 +16,12 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.interpreters import pxla
 from jax.sharding import PartitionSpec as P
 
 
 def _current_mesh():
-    m = pxla.thread_resources.env.physical_mesh
-    if m is None or m.empty:
-        return None
-    return m
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _resolve(axis: Optional[str], mesh) -> Optional[Tuple[str, ...]]:
@@ -83,7 +80,4 @@ def hint(x: jax.Array, *axes: Optional[str]) -> jax.Array:
             spec.append(names if len(names) > 1 else names[0])
         else:
             spec.append(None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:       # outside jit, or incompatible context
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))

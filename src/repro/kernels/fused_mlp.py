@@ -15,6 +15,9 @@ analogue is the BlockSpec); the systolic MXU replaces the PE array, so the
 
 Grid: (T/bt, F/bf).  The f axis is innermost, so the fp32 accumulator
 tile persists in the output ref across the f sweep (revisiting pattern).
+Rows are zero-padded to a multiple of ``block_t``.  ``block_f`` is an
+upper bound: the f tile is the largest 128-aligned one under it that
+divides F, so d_ff = 11008 = 43 * 256 (qwen2.5-3b) runs with bf = 256.
 """
 from __future__ import annotations
 
@@ -23,6 +26,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+def fit_block(n: int, bound: int, align: int) -> int:
+    """The largest tile <= ``bound`` that divides ``n``: ``n`` itself when
+    it fits, else ``bound`` or a multiple of ``align`` below it."""
+    if n <= bound:
+        return n
+    if n % bound == 0:
+        return bound
+    for b in range(bound - bound % align, 0, -align):
+        if n % b == 0:
+            return b
+    raise ValueError(f"no {align}-aligned tile <= {bound} divides {n}")
 
 
 def _fused_mlp_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, n_f: int):
@@ -52,9 +68,11 @@ def fused_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     T, D = x.shape
     F = w_gate.shape[1]
     bt = min(block_t, T)
-    bf = min(block_f, F)
-    assert T % bt == 0 and F % bf == 0, (T, F, bt, bf)
-    grid = (T // bt, F // bf)
+    bf = fit_block(F, block_f, 128)
+    pad = -T % bt
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    grid = ((T + pad) // bt, F // bf)
 
     out = pl.pallas_call(
         functools.partial(_fused_mlp_kernel, n_f=grid[1]),
@@ -66,7 +84,7 @@ def fused_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
             pl.BlockSpec((bf, D), lambda t, f: (f, 0)),       # W_down row
         ],
         out_specs=pl.BlockSpec((bt, D), lambda t, f: (t, 0)),  # revisited
-        out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((T + pad, D), jnp.float32),
         interpret=interpret,
     )(x, w_gate, w_up, w_down)
-    return out.astype(x.dtype)
+    return out[:T].astype(x.dtype)

@@ -19,15 +19,19 @@ structure as ``rglru_scan``.
 Engines (``maxplus_scan(..., engine=...)``):
 
   * ``"pallas"`` — the chunked kernel above; ``interpret=True`` runs it on
-    CPU (dtype-polymorphic, so float64 works in interpret mode; TPU
-    hardware is float32).
+    CPU (dtype-polymorphic, so float64 works in interpret mode).  The
+    TPU compiler refuses it (no Mosaic lowering for ``cumsum``, and a
+    ``(1, L)`` block breaks the (8, 128) tiling at B > 1), so on a TPU
+    an explicit ``"pallas"`` raises that error; it never drops to
+    interpret mode there.
   * ``"xla"``    — ``lax.associative_scan`` over the max-plus semiring
     pairs ``(s, u) . (s', u') = (s + s', max(u + s', u'))``.
-  * ``"numpy"``  — the same closed form in numpy (no jax dependency).
-  * ``"auto"``   — ``REPRO_MAXPLUS_ENGINE`` env override, else pallas on
-    a real accelerator backend (TPU/GPU), numpy otherwise: on CPU the
-    jax engines' dispatch overhead loses to the numpy closed form
-    (docs/engines.md), so simulation resolves independently of pricing.
+  * ``"numpy"``  — the same closed form in numpy.
+  * ``"auto"``   — ``REPRO_MAXPLUS_ENGINE`` env override, else numpy on
+    every backend: on CPU the jax engines' dispatch overhead loses to
+    the numpy closed form (docs/engines.md); on a TPU the kernel does
+    not compile, and the float64 the jax engines need would flip
+    ``jax_enable_x64`` in a process that may be serving a model.
 
 ``maxplus_scan_reference`` is the scalar loop both parity suites pin the
 engines against.
@@ -44,16 +48,11 @@ import math
 import os
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:                                    # jax is optional at this layer
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_JAX = True
-except Exception:                       # noqa: BLE001 - any import failure
-    _HAVE_JAX = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _X64_OK: Optional[bool] = None
 
@@ -66,8 +65,6 @@ def ensure_x64() -> None:
     that mode rather than drift from the numpy reference.
     """
     global _X64_OK
-    if not _HAVE_JAX:
-        raise RuntimeError("jax is not available; use engine='numpy'")
     if _X64_OK is None:
         jax.config.update("jax_enable_x64", True)
         probe = jnp.asarray(np.float64(2.0 ** 53 + 1.0))
@@ -106,57 +103,57 @@ def _maxplus_numpy(u: np.ndarray, s: np.ndarray, h0: float) -> np.ndarray:
 # Pallas kernel (rglru_scan's grid/block structure)
 # ---------------------------------------------------------------------------
 
-if _HAVE_JAX:
+def _maxplus_kernel(u_ref, s_ref, h0_ref, y_ref, h_ref, *,
+                    n_chunks: int):
+    cb = pl.program_id(1)
 
-    def _maxplus_kernel(u_ref, s_ref, h0_ref, y_ref, h_ref, *,
-                        n_chunks: int):
-        cb = pl.program_id(1)
+    @pl.when(cb == 0)
+    def _init():
+        h_ref[...] = h0_ref[...]
 
-        @pl.when(cb == 0)
-        def _init():
-            h_ref[...] = h0_ref[...]
+    u = u_ref[...]                        # (1, L)
+    s = s_ref[...]                        # (1, L)
+    c = h_ref[...]                        # (1, 1) carry in scratch
+    P = jnp.cumsum(s, axis=1)
+    q = jax.lax.cummax(u - P, axis=1)
+    y = P + jnp.maximum(q, c)
+    y_ref[...] = y
+    h_ref[...] = y[:, -1:]
 
-        u = u_ref[...]                        # (1, L)
-        s = s_ref[...]                        # (1, L)
-        c = h_ref[...]                        # (1, 1) carry in scratch
-        P = jnp.cumsum(s, axis=1)
-        q = jax.lax.cummax(u - P, axis=1)
-        y = P + jnp.maximum(q, c)
-        y_ref[...] = y
-        h_ref[...] = y[:, -1:]
 
-    @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-    def maxplus_chunked(u: "jax.Array", s: "jax.Array", h0: "jax.Array", *,
-                        chunk: int = 256, interpret: bool = False):
-        """u, s: (B, T); h0: (B, 1) -> x: (B, T).  T must divide by chunk
-        (callers pad with u = -inf, s = 0 — a max-plus no-op)."""
-        B, T = u.shape
-        L = min(chunk, T)
-        assert T % L == 0
-        grid = (B, T // L)
-        return pl.pallas_call(
-            functools.partial(_maxplus_kernel, n_chunks=grid[1]),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, L), lambda b_, c_: (b_, c_)),
-                pl.BlockSpec((1, L), lambda b_, c_: (b_, c_)),
-                pl.BlockSpec((1, 1), lambda b_, c_: (b_, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, L), lambda b_, c_: (b_, c_)),
-            out_shape=jax.ShapeDtypeStruct((B, T), u.dtype),
-            scratch_shapes=[pltpu.VMEM((1, 1), u.dtype)],
-            interpret=interpret,
-        )(u, s, h0)
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def maxplus_chunked(u: "jax.Array", s: "jax.Array", h0: "jax.Array", *,
+                    chunk: int = 256, interpret: bool = False):
+    """u, s: (B, T); h0: (B, 1) -> x: (B, T).  T must divide by chunk
+    (callers pad with u = -inf, s = 0 — a max-plus no-op)."""
+    B, T = u.shape
+    L = min(chunk, T)
+    assert T % L == 0
+    grid = (B, T // L)
+    return pl.pallas_call(
+        functools.partial(_maxplus_kernel, n_chunks=grid[1]),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, L), lambda b_, c_: (b_, c_)),
+            pl.BlockSpec((1, L), lambda b_, c_: (b_, c_)),
+            pl.BlockSpec((1, 1), lambda b_, c_: (b_, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, L), lambda b_, c_: (b_, c_)),
+        out_shape=jax.ShapeDtypeStruct((B, T), u.dtype),
+        scratch_shapes=[pltpu.VMEM((1, 1), u.dtype)],
+        interpret=interpret,
+    )(u, s, h0)
 
-    @jax.jit
-    def _maxplus_xla(u: "jax.Array", s: "jax.Array", h0: "jax.Array"):
-        """(B, T) associative scan over the max-plus semiring pairs."""
-        def combine(a, b):
-            s1, u1 = a
-            s2, u2 = b
-            return s1 + s2, jnp.maximum(u1 + s2, u2)
-        S, U = jax.lax.associative_scan(combine, (s, u), axis=1)
-        return jnp.maximum(h0 + S, U)
+
+@jax.jit
+def _maxplus_xla(u: "jax.Array", s: "jax.Array", h0: "jax.Array"):
+    """(B, T) associative scan over the max-plus semiring pairs."""
+    def combine(a, b):
+        s1, u1 = a
+        s2, u2 = b
+        return s1 + s2, jnp.maximum(u1 + s2, u2)
+    S, U = jax.lax.associative_scan(combine, (s, u), axis=1)
+    return jnp.maximum(h0 + S, U)
 
 
 # ---------------------------------------------------------------------------
@@ -172,14 +169,7 @@ def _resolve_engine(engine: str) -> str:
     env = os.environ.get("REPRO_MAXPLUS_ENGINE", "").strip().lower()
     if env in ("pallas", "xla", "numpy"):
         return env
-    if not _HAVE_JAX:
-        return "numpy"
-    # accelerator-only dispatch: on CPU the host round-trips + dispatch
-    # overhead of both jax engines lose to the numpy closed form (a
-    # measured 0.13x on sim_speed_jax — docs/engines.md), so "auto" only
-    # picks a jax engine when a real accelerator backend is attached
-    return ("pallas" if jax.default_backend() in ("tpu", "gpu")
-            else "numpy")
+    return "numpy"
 
 
 def maxplus_scan(u, s, h0: float = -math.inf, engine: str = "auto",

@@ -2,20 +2,19 @@
 
 ``use_pallas`` can be forced (e.g. interpret-mode validation in tests);
 by default kernels run only on TPU backends, keeping CPU smoke tests on
-the exact reference path.
+the exact reference path.  The chunked scans (``wkv6``, ``rglru_chunked``)
+have no dispatcher: the TPU compiler refuses them (no Mosaic lowering
+for ``cumsum``), and the models run their ``lax`` scans instead.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention
 from .fused_mlp import fused_mlp
-from .rglru_scan import rglru_chunked
-from .rwkv6_scan import wkv6
 
 
 def _on_tpu() -> bool:
@@ -49,20 +48,3 @@ def attention_op(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return flash_attention(q, k, v, causal=causal, window=window,
                            interpret=interpret)
 
-
-def wkv6_op(r, k, v, w, u, use_pallas: Optional[bool] = None,
-            chunk: int = 64, interpret: bool = False):
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
-        return ref.wkv6_ref(r, k, v, w, u)
-    return wkv6(r, k, v, w, u, chunk=chunk, interpret=interpret)
-
-
-def rglru_op(a, b, use_pallas: Optional[bool] = None, chunk: int = 64,
-             interpret: bool = False):
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
-        return ref.rglru_ref(a, b)
-    return rglru_chunked(a, b, chunk=chunk, interpret=interpret)

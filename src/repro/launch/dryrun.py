@@ -141,7 +141,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         args = (params_shape, dspecs["tokens"], dspecs["cache"],
                 dspecs["index"])
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
 

@@ -1,8 +1,12 @@
 """Serving launcher: continuous-batching engine over a slot pool.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b [--smoke] \
         --requests 6 --max-new 12 [--kv-quant] \
         [--plan] [--plan-store DIR]
+
+Without ``--smoke`` the architecture's published config is served (for
+qwen2.5-3b: 36 layers, d_model 2048, bf16 weights; one TPU v5e holds
+it).  The compile cache goes where ``launch.compile_cache`` says.
 
 ``--plan`` attaches the PipeOrgan accelerator plan for the model's decode
 step (a ``PlanRequest`` through the shared planner facade); with
@@ -20,8 +24,8 @@ one ``ServeEngine`` per tenant in the resolved plan's mode:
     PYTHONPATH=src python -m repro.launch.serve --smoke \
         --tenants "qwen2.5-3b:2:1,qwen2.5-3b:1" [--plan-store DIR]
 
-Production deployments replace --smoke with the sharded production mesh
-(the same serve_step the dry-run compiles for decode_32k / long_500k).
+The engine jits ``runtime.steps.make_serve_step``: the same serve_step
+the dry-run compiles for decode_32k / long_500k.
 """
 from __future__ import annotations
 
@@ -34,6 +38,7 @@ import jax
 from repro.configs import ARCHS, get_config
 from repro.core import (MultiTenantRequest, PAPER_HW, PlanRequest, PlanStore,
                         TenantSpec, Topology, resolve_multi_tenant)
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import init_model
 from repro.runtime.serve_loop import (AdmissionScheduler, Lane, Request,
                                       ServeEngine, decode_graph)
@@ -116,10 +121,11 @@ def serve_tenants(args) -> None:
               f"mean finish tick {st.get(f'{name}.mean_finish_tick', 0):.1f}")
 
 
-def main() -> None:
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen2.5-3b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (2 layers, width 64)")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)
@@ -133,8 +139,12 @@ def main() -> None:
     ap.add_argument("--tenants", default=None, metavar="SPEC",
                     help='serve co-resident tenants on one substrate: '
                          '"arch[:share[:priority]],..." (>= 2 entries)')
-    args = ap.parse_args()
+    return ap
 
+
+def main() -> None:
+    args = parser().parse_args()
+    place_compile_cache()
     if args.tenants:
         serve_tenants(args)
         return

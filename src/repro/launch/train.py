@@ -8,9 +8,10 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b \
         --shape train_4k [--multipod]
 
-On this CPU container the production path is validated via
+The production mesh is validated without its 256/512 chips by
 ``repro.launch.dryrun`` (compile-only); the launcher itself is the same
-code path a TPU deployment runs.
+code path a TPU deployment runs.  The compile cache goes where
+``launch.compile_cache`` says.
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ import argparse
 
 from repro.configs import ARCHS, SHAPES, get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.steps import pick_microbatches
@@ -40,6 +42,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     shape = SHAPES[args.shape]

@@ -85,7 +85,11 @@ def _init_decoder_layer(key: jax.Array, cfg: ModelConfig) -> Params:
     return p
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def init_model(key: jax.Array, cfg: ModelConfig) -> Params:
+    """Seeded random weights, built as one jitted program: eagerly, the
+    per-layer trees and their stacked copy would all sit on the device
+    at once (two copies of the weights at the peak)."""
     if cfg.arch_kind == "encdec":
         return _init_whisper(key, cfg)
     ks = jax.random.split(key, cfg.n_layers + 3)
@@ -115,8 +119,8 @@ def init_model(key: jax.Array, cfg: ModelConfig) -> Params:
             layers.append(p)
         params["layers"] = layers            # heterogeneous: keep as list
         return params
-    params["layers"] = _stack(
-        [_init_decoder_layer(ks[3 + l], cfg) for l in range(cfg.n_layers)])
+    params["layers"] = jax.vmap(lambda k: _init_decoder_layer(k, cfg))(
+        ks[3:])
     return params
 
 

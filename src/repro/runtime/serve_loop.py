@@ -25,7 +25,8 @@ from repro.core import (Graph, HWConfig, PlanAPIDeprecationWarning,
                         PlanRequest, PlanSchemaError, PlanStore, Topology,
                         gemm, get_planner)
 from repro.models.common import ModelConfig
-from repro.models.transformer import decode_step, init_cache, zero_cache_slot
+from repro.models.transformer import init_cache, zero_cache_slot
+from repro.runtime.steps import make_serve_step
 
 
 def decode_graph(cfg: ModelConfig) -> Graph:
@@ -88,7 +89,7 @@ class ServeEngine:
         # slots that have ever held a request: their cache rows must be
         # wiped before reuse so the next occupant can't attend to them
         self._slot_dirty = np.zeros(batch_slots, bool)
-        self._step = jax.jit(self._device_step)
+        self._step = jax.jit(make_serve_step(cfg))
         self.ticks = 0
         self.truncated = False
         # optional accelerator plan for this model's decode step.  The
@@ -126,12 +127,6 @@ class ServeEngine:
                 self.plan_source = "planner"
                 if plan_store is not None:
                     plan_store.save(plan_request, self.plan)
-
-    # -- device program ------------------------------------------------------
-    def _device_step(self, params, cache, tokens, index):
-        logits, cache = decode_step(params, self.cfg, tokens, cache, index)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return nxt, cache
 
     # -- scheduling ----------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -175,9 +170,9 @@ class ServeEngine:
         # level, not the pool-wide maximum (which would let a fresh
         # request attend to the previous occupant's cache rows)
         index = jnp.asarray(self.pos, jnp.int32)
-        nxt, self.cache = self._step(self.params, self.cache,
-                                     jnp.asarray(feed), index)
-        nxt = np.asarray(nxt)
+        nxt, self.cache = self._step(self.params, jnp.asarray(feed),
+                                     self.cache, index)
+        nxt = np.asarray(nxt)[:, 0]
 
         finished = []
         for slot, req in enumerate(self.active):

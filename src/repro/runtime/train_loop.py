@@ -1,6 +1,6 @@
 """Fault-tolerant training loop.
 
-Design for 1000+ nodes (see DESIGN.md §6):
+Design for 1000+ nodes:
   * step-atomic async checkpoints every ``ckpt_every`` steps;
   * on step failure (device loss / preemption / injected fault) the loop
     re-forms the mesh from the surviving devices (elastic re-mesh: the
@@ -26,7 +26,7 @@ from repro.data.pipeline import DataConfig, TokenDataset
 from repro.distributed.sharding import batch_shardings, params_shardings, replicated
 from repro.models.common import ModelConfig
 from repro.models.transformer import init_model
-from repro.optim.adamw import AdamWConfig, init_state
+from repro.optim.adamw import AdamWConfig, AdamWState, init_state
 from repro.runtime.steps import make_train_step
 
 
@@ -41,6 +41,15 @@ class TrainLoopConfig:
     max_failures: int = 3
 
 
+class NodeFailure(RuntimeError):
+    """A simulated node failure (raised by ``FaultInjector``)."""
+
+
+#: what the loop recovers from: device/runtime failures and the injected
+#: fault.  Trace and compile errors are raised by ``_build``, outside it.
+RECOVERABLE = (NodeFailure, jax.errors.JaxRuntimeError)
+
+
 class FaultInjector:
     """Test hook: raise at a chosen step to simulate a node failure."""
 
@@ -51,20 +60,21 @@ class FaultInjector:
     def check(self, step: int) -> None:
         if self.fail_at is not None and step == self.fail_at and not self.fired:
             self.fired = True
-            raise RuntimeError(f"injected node failure at step {step}")
+            raise NodeFailure(f"injected node failure at step {step}")
 
 
 def _build(cfg: ModelConfig, opt_cfg: AdamWConfig, loop: TrainLoopConfig,
            mesh, data_cfg: DataConfig):
+    """Initial state on ``mesh`` plus the step, compiled ahead of time so
+    that a trace or compile error raises here, never inside the loop's
+    failure handling.  Params and optimizer state are donated to the step
+    and come back with the shardings they went in with."""
     daxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     params_shape = jax.eval_shape(
         lambda: init_model(jax.random.PRNGKey(loop.seed), cfg))
     p_shard = params_shardings(cfg, params_shape, mesh)
-    with mesh:
-        params = jax.jit(lambda: init_model(jax.random.PRNGKey(loop.seed),
-                                            cfg), out_shardings=p_shard)()
-        # moments mirror the (already FSDP/TP-sharded) params => ZeRO states
-        opt_state = jax.jit(init_state)(params)
+    # moments mirror the (already FSDP/TP-sharded) params => ZeRO states
+    o_shard = AdamWState(step=replicated(mesh), mu=p_shard, nu=p_shard)
     step_fn = make_train_step(cfg, opt_cfg, microbatches=loop.microbatches,
                               data_axes=daxes)
     specs = {
@@ -74,8 +84,15 @@ def _build(cfg: ModelConfig, opt_cfg: AdamWConfig, loop: TrainLoopConfig,
             (data_cfg.global_batch, data_cfg.seq_len), jax.numpy.int32),
     }
     b_shard = batch_shardings(cfg, specs, mesh)
-    jitted = jax.jit(step_fn)
-    return params, opt_state, jitted, b_shard, p_shard
+    with jax.set_mesh(mesh):
+        params = jax.jit(lambda: init_model(jax.random.PRNGKey(loop.seed),
+                                            cfg), out_shardings=p_shard)()
+        opt_state = jax.jit(init_state, out_shardings=o_shard)(params)
+        step = jax.jit(step_fn, in_shardings=(p_shard, o_shard, b_shard),
+                       out_shardings=(p_shard, o_shard, replicated(mesh)),
+                       donate_argnums=(0, 1)
+                       ).lower(params, opt_state, specs).compile()
+    return params, opt_state, step, b_shard, p_shard, o_shard
 
 
 def train(cfg: ModelConfig, opt_cfg: AdamWConfig, loop: TrainLoopConfig,
@@ -91,36 +108,32 @@ def train(cfg: ModelConfig, opt_cfg: AdamWConfig, loop: TrainLoopConfig,
     step = 0
 
     mesh = mesh_fn()
-    params, opt_state, jitted, b_shard, p_shard = _build(
+    params, opt_state, step_exe, b_shard, p_shard, o_shard = _build(
         cfg, opt_cfg, loop, mesh, data_cfg)
 
     # resume
-    def _restore_all(mesh, params, opt_state, p_shard):
+    def _restore_all(params, opt_state, p_shard, o_shard):
         last = latest_step(loop.ckpt_dir)
         if last is None:
             return params, opt_state, 0
-        o_shard = type(opt_state)(step=replicated(mesh), mu=p_shard,
-                                  nu=p_shard)
-        with mesh:
-            tree = restore(loop.ckpt_dir, last,
-                           {"params": params, "opt": opt_state},
-                           {"params": p_shard, "opt": o_shard})
+        tree = restore(loop.ckpt_dir, last,
+                       {"params": params, "opt": opt_state},
+                       {"params": p_shard, "opt": o_shard})
         print(f"[train] resumed from step {last}")
         return tree["params"], tree["opt"], last
 
     if loop.ckpt_dir:
-        params, opt_state, step = _restore_all(mesh, params, opt_state,
-                                               p_shard)
+        params, opt_state, step = _restore_all(params, opt_state, p_shard,
+                                               o_shard)
 
     while step < loop.steps:
         try:
             host = ds.global_batch_at(step)
-            with mesh:
-                batch = {k: jax.device_put(v, b_shard[k])
-                         for k, v in host.items()}
-                if fault is not None:
-                    fault.check(step)
-                params, opt_state, metrics = jitted(params, opt_state, batch)
+            batch = {k: jax.device_put(v, b_shard[k])
+                     for k, v in host.items()}
+            if fault is not None:
+                fault.check(step)
+            params, opt_state, metrics = step_exe(params, opt_state, batch)
             step += 1
             if step % loop.log_every == 0 or step == loop.steps:
                 m = {k: float(np.asarray(v)) for k, v in metrics.items()}
@@ -130,7 +143,7 @@ def train(cfg: ModelConfig, opt_cfg: AdamWConfig, loop: TrainLoopConfig,
             if ckpt and step % loop.ckpt_every == 0:
                 ckpt.save_async(step, {"params": params, "opt": opt_state},
                                 {"model": cfg.name})
-        except Exception as e:  # noqa: BLE001 — node failure path
+        except RECOVERABLE as e:
             failures += 1
             if failures > loop.max_failures:
                 raise
@@ -139,11 +152,11 @@ def train(cfg: ModelConfig, opt_cfg: AdamWConfig, loop: TrainLoopConfig,
             if ckpt:
                 ckpt.wait()
             mesh = mesh_fn()  # elastic: survivors form the new mesh
-            params, opt_state, jitted, b_shard, p_shard = _build(
+            params, opt_state, step_exe, b_shard, p_shard, o_shard = _build(
                 cfg, opt_cfg, loop, mesh, data_cfg)
             if loop.ckpt_dir and latest_step(loop.ckpt_dir) is not None:
                 params, opt_state, step = _restore_all(
-                    mesh, params, opt_state, p_shard)
+                    params, opt_state, p_shard, o_shard)
             else:
                 step = 0
 

@@ -327,3 +327,46 @@ def test_maxplus_engine_validated_before_empty_early_return():
     assert out.shape == (3, 0)
     assert maxplus_scan(np.zeros(0), np.zeros(0), engine="numpy").shape \
         == (0,)
+
+
+def test_tpu_backend_auto_engines_stay_numpy(monkeypatch):
+    """On a TPU backend "auto" prices and simulates with numpy and never
+    probes the jax pricer, whose float64 guard would turn on
+    jax_enable_x64 in the process serving the model."""
+    import jax
+
+    from repro.core import plan_api, planner
+    from repro.kernels.maxplus_scan import _resolve_engine
+
+    def probe():
+        raise AssertionError("jax pricer probed on a TPU backend")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(plan_api, "jax_engine_available", probe)
+    monkeypatch.delenv("REPRO_MAXPLUS_ENGINE", raising=False)
+    assert PlanRequest(_chain(2), hw=SIM_HW).engine == "numpy"
+    assert planner.resolve_engine("auto") == "batch"
+    assert _resolve_engine("auto") == "numpy"
+
+
+@jax_ok
+def test_explicit_pallas_maxplus_on_tpu_compiles_not_interprets(monkeypatch):
+    """An explicit engine="pallas" on a TPU backend hands the kernel to
+    the chip's compiler (which refuses it); it never drops to interpret
+    mode."""
+    import importlib
+
+    import jax
+
+    mp = importlib.import_module("repro.kernels.maxplus_scan")
+    seen = []
+
+    def fake_chunked(u, s, h0, *, chunk, interpret):
+        seen.append(interpret)
+        raise ValueError("refused by the TPU compiler")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mp, "maxplus_chunked", fake_chunked)
+    with pytest.raises(ValueError, match="refused"):
+        mp.maxplus_scan(np.zeros((2, 8)), np.ones((2, 8)), engine="pallas")
+    assert seen == [False]

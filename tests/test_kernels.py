@@ -8,7 +8,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_mlp import fused_mlp
-from repro.kernels.ops import attention_op, mlp_block, rglru_op, wkv6_op
+from repro.kernels.ops import mlp_block
 from repro.kernels.rglru_scan import rglru_chunked
 from repro.kernels.rwkv6_scan import wkv6
 
@@ -28,6 +28,7 @@ def _k(i):
     (128, 64, 256, 64, 128),
     (256, 128, 512, 128, 256),
     (96, 48, 96, 32, 48),       # non-power-of-two dims
+    (100, 32, 384, 32, 256),    # rows padded to the tile; bf 256 -> 128
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_mlp_sweep(T, D, F, bt, bf, dtype):

@@ -258,3 +258,13 @@ def test_scheduler_truncation_signal():
         sched.run(max_ticks=2)
     assert sched.stats()["truncated"] == 1.0
     assert any("truncated" in str(w.message) for w in caught)
+
+
+def test_serve_cli_builds_published_config_without_smoke():
+    from repro.launch.serve import parser
+
+    args = parser().parse_args(["--arch", "qwen2.5-3b"])
+    assert not args.smoke
+    cfg = get_config(args.arch, smoke=args.smoke)
+    assert (cfg.n_layers, cfg.d_model, cfg.vocab) == (36, 2048, 151936)
+    assert parser().parse_args(["--smoke"]).smoke

@@ -61,6 +61,52 @@ def test_train_resume_is_seamless():
                                    atol=1e-5, rtol=1e-5)
 
 
+def test_train_raises_trace_error_without_retry(monkeypatch):
+    """A trace-time error is a bug, not a node failure: train() raises it
+    on the first attempt instead of re-forming the mesh and retrying."""
+    from repro.runtime import train_loop
+
+    def broken_step(*args, **kwargs):
+        def step(params, opt_state, batch):
+            raise TypeError("bad step")
+        return step
+
+    monkeypatch.setattr(train_loop, "make_train_step", broken_step)
+    meshes = []
+
+    def mesh_fn():
+        meshes.append(make_host_mesh())
+        return meshes[-1]
+
+    cfg = get_config("qwen2.5-3b", smoke=True)
+    data = DataConfig(seq_len=8, global_batch=2, vocab=cfg.vocab)
+    loop = TrainLoopConfig(steps=2, log_every=1)
+    with pytest.raises(TypeError, match="bad step"):
+        train(cfg, AdamWConfig(), loop, mesh_fn, data)
+    assert len(meshes) == 1
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache goes to one fixed, gitignored directory of the checkout."""
+    from repro.launch.compile_cache import DEFAULT_DIR, place_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert place_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert place_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    root = DEFAULT_DIR.parent
+    assert (root / "pyproject.toml").exists()
+    assert f"{DEFAULT_DIR.name}/" in (root / ".gitignore").read_text()
+
+
 # ---------------------------------------------------------------------------
 # checkpoint store
 # ---------------------------------------------------------------------------

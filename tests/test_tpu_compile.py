@@ -1,0 +1,91 @@
+"""Compile the main path for a described TPU v5e chip (nothing runs).
+
+The TPU compiler is installed even where no chip is attached, and it
+refuses what interpret-mode tests cannot see: misaligned tiles, too much
+VMEM, programs that do not fit HBM.  The chip is described inside a
+module-scoped fixture, never at import: only one process at a time may
+load the TPU library, and every test worker imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.fused_mlp import fit_block
+from repro.kernels.ops import mlp_block
+from repro.models import init_model
+from repro.models.transformer import init_cache
+from repro.runtime.steps import make_serve_step
+
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to a persistent cache but
+    # cannot be read back without the chip: keep the cache out of it.
+    # x64 stays off as in a serving process: a planner test earlier in
+    # this worker may have turned it on, and under it the kernels' index
+    # maps return i64, which Mosaic refuses.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    with jax.enable_x64(False):
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_serve_decode_step_fits_one_chip(one_chip):
+    """ServeEngine's step at qwen2.5-3b's published widths, 4 slots of
+    2048 tokens, compiles for one v5e and fits its 16 GB."""
+    cfg = get_config("qwen2.5-3b")
+    params = _on(one_chip, jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), cfg)))
+    cache = _on(one_chip, jax.eval_shape(lambda: init_cache(cfg, 4, 2048)))
+    tokens = jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=one_chip)
+    index = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_serve_step(cfg)).lower(
+        params, tokens, cache, index).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 6 * 10**9     # the bf16 weights
+    assert total < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("tokens", [37, 300])
+def test_fused_mlp_compiles_at_qwen_widths(one_chip, tokens):
+    """The model path's fused SwiGLU at D=2048, F=11008: the tile it
+    picks (bf = 256; the old fixed 512 does not divide 11008), with rows
+    whole (37) or padded to the 256-row tile (300), lowers to a Mosaic
+    kernel."""
+    D, F = 2048, 11008
+    assert fit_block(F, 512, 128) == 256
+    x = jax.ShapeDtypeStruct((1, tokens, D), jnp.bfloat16, sharding=one_chip)
+    w_in = jax.ShapeDtypeStruct((D, F), jnp.bfloat16, sharding=one_chip)
+    w_out = jax.ShapeDtypeStruct((F, D), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: mlp_block(*a, use_pallas=True, interpret=False)
+    ).lower(x, w_in, w_in, w_out).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    q = jax.ShapeDtypeStruct((32, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention(q, k, v)).lower(
+        q, q, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
