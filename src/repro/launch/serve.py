@@ -14,6 +14,11 @@ step (a ``PlanRequest`` through the shared planner facade); with
 serialized ``PlanArtifact``s, so a warm store serves with zero planner
 invocations at startup — the offline-plan -> online-serve path.
 
+At the end of a run the engine's counters from ``ServeEngine.stats()``
+are printed, one line per lane: requests admitted, slot wipes, prompt
+and decode slot-ticks, the longest queue at a tick's start, and spans
+the telemetry ring overwrote.
+
 ``--tenants "name:share[:priority],..."`` serves several architectures as
 co-resident tenants on one substrate instead: their decode graphs go
 through ``core.multi_tenant.resolve_multi_tenant`` (spatial column bands
@@ -42,6 +47,16 @@ from repro.launch.compile_cache import place_compile_cache
 from repro.models import init_model
 from repro.runtime.serve_loop import (AdmissionScheduler, Lane, Request,
                                       ServeEngine, decode_graph)
+
+
+COUNTERS = ("admitted", "wipes", "prompt_tokens", "decode_tokens",
+            "queue_peak", "spans_dropped")
+
+
+def counter_line(engine: ServeEngine) -> str:
+    """The engine's counters from ``stats()``, for the end of a run."""
+    st = engine.stats()
+    return ", ".join(f"{k} {st[k]:.0f}" for k in COUNTERS)
 
 
 def parse_tenants(spec: str) -> list:
@@ -118,7 +133,8 @@ def serve_tenants(args) -> None:
     st = sched.stats()
     for name in sorted(engines):
         print(f"  {name}: {st[f'{name}.completed']:.0f} done, "
-              f"mean finish tick {st.get(f'{name}.mean_finish_tick', 0):.1f}")
+              f"mean finish tick {st.get(f'{name}.mean_finish_tick', 0):.1f}"
+              f"; {counter_line(engines[name])}")
 
 
 def parser() -> argparse.ArgumentParser:
@@ -172,6 +188,7 @@ def main() -> None:
     print(f"served {len(done)} requests / {total} tokens in {dt*1e3:.0f} ms "
           f"({total/dt:.0f} tok/s, {args.slots} slots, "
           f"kv_quant={cfg.kv_quant})")
+    print(f"engine: {counter_line(engine)}")
     if engine.plan is not None:
         print(f"decode plan: source={engine.plan_source} "
               f"{engine.plan.latency_cycles:.3e} cycles/token, "

@@ -150,16 +150,17 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
     B, S, _ = x.shape
     if positions.ndim == 1:
         positions = jnp.broadcast_to(positions[None, :], (B, S))
-    q, k, v = _qkv(p, x, cfg)
-    if rope:
-        if mrope_positions is not None:
-            q = apply_mrope(q, mrope_positions, cfg.rope_theta,
-                            cfg.mrope_sections)
-            k = apply_mrope(k, mrope_positions, cfg.rope_theta,
-                            cfg.mrope_sections)
-        else:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _qkv(p, x, cfg)
+        if rope:
+            if mrope_positions is not None:
+                q = apply_mrope(q, mrope_positions, cfg.rope_theta,
+                                cfg.mrope_sections)
+                k = apply_mrope(k, mrope_positions, cfg.rope_theta,
+                                cfg.mrope_sections)
+            else:
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k = apply_rope(k, positions, cfg.rope_theta)
 
     if cache is not None:
         # dynamic_update_slice wants every index in one dtype; under
@@ -179,35 +180,40 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
             def place(c, new):
                 return jax.lax.dynamic_update_slice(
                     c, new, (zero, cache_index, zero, zero))
-        if cfg.kv_quant:
-            # int8 cache with per-vector scales: quantize the new slice,
-            # dequantize on read (fused on TPU; HBM moves 1B/elem not 2)
-            ck, cv, ks, vs = cache
-            k_s = jnp.max(jnp.abs(k), axis=-1, keepdims=True) / 127.0 + 1e-8
-            v_s = jnp.max(jnp.abs(v), axis=-1, keepdims=True) / 127.0 + 1e-8
-            k_q = jnp.round(k / k_s).astype(jnp.int8)
-            v_q = jnp.round(v / v_s).astype(jnp.int8)
-            ck = place(ck, k_q)
-            cv = place(cv, v_q)
-            ks = place(ks, k_s.astype(ks.dtype))
-            vs = place(vs, v_s.astype(vs.dtype))
-            k = ck.astype(x.dtype) * ks.astype(x.dtype)
-            v = cv.astype(x.dtype) * vs.astype(x.dtype)
-            new_cache = (ck, cv, ks, vs)
-        else:
-            ck, cv = cache
-            ck = place(ck, k.astype(ck.dtype))
-            cv = place(cv, v.astype(cv.dtype))
-            k, v = ck, cv
-            new_cache = (ck, cv)
+        with jax.named_scope("attn.kv_cache"):
+            if cfg.kv_quant:
+                # int8 cache with per-vector scales: quantize the new
+                # slice, dequantize on read (fused on TPU; HBM moves
+                # 1B/elem not 2)
+                ck, cv, ks, vs = cache
+                k_s = (jnp.max(jnp.abs(k), axis=-1, keepdims=True) / 127.0
+                       + 1e-8)
+                v_s = (jnp.max(jnp.abs(v), axis=-1, keepdims=True) / 127.0
+                       + 1e-8)
+                k_q = jnp.round(k / k_s).astype(jnp.int8)
+                v_q = jnp.round(v / v_s).astype(jnp.int8)
+                ck = place(ck, k_q)
+                cv = place(cv, v_q)
+                ks = place(ks, k_s.astype(ks.dtype))
+                vs = place(vs, v_s.astype(vs.dtype))
+                k = ck.astype(x.dtype) * ks.astype(x.dtype)
+                v = cv.astype(x.dtype) * vs.astype(x.dtype)
+                new_cache = (ck, cv, ks, vs)
+            else:
+                ck, cv = cache
+                ck = place(ck, k.astype(ck.dtype))
+                cv = place(cv, v.astype(cv.dtype))
+                k, v = ck, cv
+                new_cache = (ck, cv)
         T = k.shape[1]
-        kpos = jnp.arange(T)[None, None, :]                # (1,1,T)
-        qpos = positions[:, :, None]                       # (B,S,1)
-        mask = kpos <= qpos                                # causal vs cache
-        fill = cache_index[:, None, None] if per_slot else cache_index
-        mask = mask & (kpos < (fill + S))
-        if window is not None:
-            mask = mask & (qpos - kpos < window)
+        with jax.named_scope("attn.core"):
+            kpos = jnp.arange(T)[None, None, :]            # (1,1,T)
+            qpos = positions[:, :, None]                   # (B,S,1)
+            mask = kpos <= qpos                            # causal vs cache
+            fill = cache_index[:, None, None] if per_slot else cache_index
+            mask = mask & (kpos < (fill + S))
+            if window is not None:
+                mask = mask & (qpos - kpos < window)
     else:
         new_cache = None
         T = S
@@ -231,8 +237,10 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
             mask = mask & (i - j < window)
         mask = jnp.broadcast_to(mask[None], (B, S, T))
 
-    out = _sdpa(q, k, v, mask, cfg)
-    out = jnp.einsum("bsh,ho->bso", out.reshape(B, S, -1), p["wo"])
+    with jax.named_scope("attn.core"):
+        out = _sdpa(q, k, v, mask, cfg)
+    with jax.named_scope("attn.out"):
+        out = jnp.einsum("bsh,ho->bso", out.reshape(B, S, -1), p["wo"])
     return out, new_cache
 
 

@@ -156,10 +156,11 @@ def _uniform_layer(cfg: ModelConfig, x, layer_p, window, positions,
                       mrope_positions=mrope_positions)
     x = x + o
     h2 = rms_norm(x, layer_p["ln2"], cfg.norm_eps)
-    if cfg.n_experts:
-        o2, aux = moe_ffn(layer_p["moe"], h2, cfg)
-    else:
-        o2 = swiglu(layer_p["mlp"], h2, cfg)
+    with jax.named_scope("ffn"):
+        if cfg.n_experts:
+            o2, aux = moe_ffn(layer_p["moe"], h2, cfg)
+        else:
+            o2 = swiglu(layer_p["mlp"], h2, cfg)
     x = x + o2
     if kv is None:
         new_cache = None
@@ -398,12 +399,13 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         x, ncache = jax.lax.scan(body, x, (params["layers"], windows, cache))
         new_cache = ncache
 
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
-    return _mask_pad_vocab(logits, cfg), new_cache
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
+        return _mask_pad_vocab(logits, cfg), new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -9,10 +9,18 @@ dry-run's serve_step is compiled.
 
 Per-slot state lives in plain arrays so the whole scheduler is
 host-driven; the device program is the single fused serve/prefill step.
+
+Each tick is a ``serve.tick`` span (``runtime.telemetry``) holding, in
+order, ``serve.refill`` (with one ``serve.wipe`` per slot wiped),
+``serve.feed``, ``serve.dispatch``, ``serve.sync`` and ``serve.retire``;
+each request leaves ``serve.queued``, ``serve.prefill`` and
+``serve.decode`` spans under its ``rid``.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
+import time
 import warnings
 from collections import deque
 from typing import Deque, Dict, List, Optional
@@ -26,6 +34,7 @@ from repro.core import (Graph, HWConfig, PlanAPIDeprecationWarning,
                         gemm, get_planner)
 from repro.models.common import ModelConfig
 from repro.models.transformer import init_cache, zero_cache_slot
+from repro.runtime import telemetry
 from repro.runtime.steps import make_serve_step
 
 
@@ -65,6 +74,12 @@ class Request:
     # filled by the engine
     output: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # time.perf_counter() at submit, placement in a slot, first output
+    # token and completion (nan until then)
+    submitted_at: float = math.nan
+    admitted_at: float = math.nan
+    first_token_at: float = math.nan
+    finished_at: float = math.nan
 
 
 class ServeEngine:
@@ -92,6 +107,12 @@ class ServeEngine:
         self._step = jax.jit(make_serve_step(cfg))
         self.ticks = 0
         self.truncated = False
+        # counters, reported by stats()
+        self.admitted = 0
+        self.wipes = 0
+        self.prompt_tokens = 0
+        self.decode_tokens = 0
+        self.queue_peak = 0
         # optional accelerator plan for this model's decode step.  The
         # resolution order is the offline-plan -> online-serve path:
         #   1. a ``plan_store`` artifact matching ``plan_request`` exactly
@@ -130,6 +151,7 @@ class ServeEngine:
 
     # -- scheduling ----------------------------------------------------------
     def submit(self, req: Request) -> None:
+        req.submitted_at = time.perf_counter()
         self.queue.append(req)
 
     def _refill(self) -> None:
@@ -137,57 +159,85 @@ class ServeEngine:
             if self.active[slot] is None and self.queue:
                 req = self.queue.popleft()
                 if self._slot_dirty[slot]:
-                    self.cache = zero_cache_slot(self.cfg, self.cache, slot)
+                    with telemetry.span("serve.wipe", req.rid):
+                        self.cache = zero_cache_slot(self.cfg, self.cache,
+                                                     slot)
+                    self.wipes += 1
                 self._slot_dirty[slot] = True
                 self.active[slot] = req
                 self.remaining_prompt[slot] = list(req.prompt)
                 self.pos[slot] = 0
                 self.generated[slot] = 0
+                self.admitted += 1
+                req.admitted_at = time.perf_counter()
+                telemetry.record("serve.queued", req.submitted_at,
+                                 req.admitted_at, req.rid)
 
     def step(self) -> List[Request]:
         """One engine tick: feed each slot its next token (prompt token if
         still prefilling, else the model's own last sample); returns any
         requests completed this tick."""
-        self._refill()
-        self.ticks += 1
-        feed = np.zeros((self.B, 1), np.int32)
-        live = np.zeros(self.B, bool)
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            live[slot] = True
-            if self.remaining_prompt[slot]:
-                feed[slot, 0] = self.remaining_prompt[slot].pop(0)
-            elif req.output:
-                feed[slot, 0] = req.output[-1]
-            else:
-                # empty prompt: nothing to condition on — feed token 0
-                # (BOS convention) so generation starts from position 0
-                feed[slot, 0] = req.prompt[-1] if req.prompt else 0
+        with telemetry.span("serve.tick"):
+            self.queue_peak = max(self.queue_peak, len(self.queue))
+            with telemetry.span("serve.refill"):
+                self._refill()
+            self.ticks += 1
+            with telemetry.span("serve.feed"):
+                feed = np.zeros((self.B, 1), np.int32)
+                for slot, req in enumerate(self.active):
+                    if req is None:
+                        continue
+                    if self.remaining_prompt[slot]:
+                        feed[slot, 0] = self.remaining_prompt[slot].pop(0)
+                    elif req.output:
+                        feed[slot, 0] = req.output[-1]
+                    else:
+                        # empty prompt: nothing to condition on — feed
+                        # token 0 (BOS convention) so generation starts
+                        # from position 0
+                        feed[slot, 0] = req.prompt[-1] if req.prompt else 0
+                # each slot decodes at its own cursor: the per-slot index
+                # vector keeps a refilled slot's writes and causal mask at
+                # *its* fill level, not the pool-wide maximum (which would
+                # let a fresh request attend to the previous occupant's
+                # cache rows)
+                index = jnp.asarray(self.pos, jnp.int32)
+                tokens = jnp.asarray(feed)
+            with telemetry.span("serve.dispatch"):
+                nxt, self.cache = self._step(self.params, tokens, self.cache,
+                                             index)
+            with telemetry.span("serve.sync"):
+                nxt = np.asarray(nxt)[:, 0]
+            with telemetry.span("serve.retire"):
+                return self._retire(nxt)
 
-        # each slot decodes at its own cursor: the per-slot index vector
-        # keeps a refilled slot's writes and causal mask at *its* fill
-        # level, not the pool-wide maximum (which would let a fresh
-        # request attend to the previous occupant's cache rows)
-        index = jnp.asarray(self.pos, jnp.int32)
-        nxt, self.cache = self._step(self.params, jnp.asarray(feed),
-                                     self.cache, index)
-        nxt = np.asarray(nxt)[:, 0]
-
+    def _retire(self, nxt: np.ndarray) -> List[Request]:
+        """Advance every live slot past the tick's token; a slot still
+        prefilling counts a prompt token, any other an output token."""
+        now = time.perf_counter()
         finished = []
         for slot, req in enumerate(self.active):
             if req is None:
                 continue
             self.pos[slot] += 1
             if self.remaining_prompt[slot]:
+                self.prompt_tokens += 1
                 continue                     # still prefilling
             tok = int(nxt[slot])
+            if not req.output:
+                req.first_token_at = now
+                telemetry.record("serve.prefill", req.admitted_at, now,
+                                 req.rid)
             req.output.append(tok)
+            self.decode_tokens += 1
             self.generated[slot] += 1
             hit_eos = req.eos_id is not None and tok == req.eos_id
             if (self.generated[slot] >= req.max_new_tokens or hit_eos
                     or self.pos[slot] >= self.max_len - 1):
                 req.done = True
+                req.finished_at = now
+                telemetry.record("serve.decode", req.first_token_at, now,
+                                 req.rid)
                 finished.append(req)
                 self.active[slot] = None
         return finished
@@ -210,12 +260,25 @@ class ServeEngine:
         return done
 
     def stats(self) -> Dict[str, float]:
-        """Engine + (when planned) accelerator-model serving estimates."""
+        """Engine counters + (when planned) accelerator-model serving
+        estimates.  ``admitted``: requests placed in a slot; ``wipes``:
+        slot wipes on placement into a used slot; ``prompt_tokens`` and
+        ``decode_tokens``: live slot-ticks that fed a prompt token and
+        produced no output, and those that produced an output token (the
+        tick of a prompt's last token yields the first output token and
+        counts there); ``queue_peak``: the longest queue at the start of
+        a tick; ``spans_dropped``: spans the telemetry ring overwrote."""
         out: Dict[str, float] = {
             "ticks": float(self.ticks),
             "queued": float(len(self.queue)),
             "active": float(sum(r is not None for r in self.active)),
             "truncated": float(self.truncated),
+            "admitted": float(self.admitted),
+            "wipes": float(self.wipes),
+            "prompt_tokens": float(self.prompt_tokens),
+            "decode_tokens": float(self.decode_tokens),
+            "queue_peak": float(self.queue_peak),
+            "spans_dropped": float(telemetry.dropped()),
         }
         if self.plan is not None:
             cyc = self.plan.latency_cycles

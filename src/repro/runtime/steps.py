@@ -105,7 +105,8 @@ def make_serve_step(cfg: ModelConfig) -> Callable:
                                                 index)
         else:
             logits, cache = decode_step(params, cfg, tokens, cache, index)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
         return nxt[:, None], cache
 
     return serve_step
