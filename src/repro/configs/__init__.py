@@ -64,7 +64,9 @@ def input_specs(cfg: ModelConfig, shape: ShapeSpec) -> Dict[str, Any]:
 
 
 def decode_input_specs(cfg: ModelConfig, shape: ShapeSpec) -> Dict[str, Any]:
-    """Stand-ins for one serve_step: one new token + a seq_len KV cache."""
+    """Stand-ins for one serve_step: one new token + a seq_len KV cache,
+    in the form ``init_cache`` stores (whisper: its own (L, B, T, Hkv,
+    hd) self-attention cache beside the encoded audio)."""
     from repro.models.transformer import init_cache
 
     B, S = shape.global_batch, shape.seq_len

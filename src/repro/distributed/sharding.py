@@ -9,8 +9,8 @@ mesh (+ an outer "pod" axis as extra data parallelism):
     "model" (TP);
   * MoE expert tensors: expert dim over "model" (EP);
   * activations: batch over ("pod","data");
-  * KV caches: batch over "data", kv-heads over "model" when divisible,
-    otherwise sequence over "model" (cache sequence-parallelism).
+  * KV caches: batch over "data", whole kv-heads over "model" when
+    divisible, otherwise sequence over "model" (cache sequence-parallelism).
 """
 from __future__ import annotations
 
@@ -121,9 +121,11 @@ def batch_shardings(cfg: ModelConfig, specs: Any, mesh: Mesh):
 def cache_shardings(cfg: ModelConfig, cache_shape: Any, mesh: Mesh):
     """KV-cache sharding for decode.
 
-    Layout (L, B, T, Hkv, hd) (or per-arch states).  Batch over "data";
-    kv-heads over "model" when divisible, else the sequence dim (cache
-    sequence parallelism — essential for GQA with few kv heads).
+    Layout (L, B, T, Hkv*hd), int8 scales (L, B, T, Hkv) (whisper keeps
+    (L, B, T, Hkv, hd); other families, per-arch states).  Batch over
+    "data"; axis 3 over "model" when the kv-heads divide it, so each
+    shard holds whole heads, else the sequence dim (cache sequence
+    parallelism — essential for GQA with few kv heads).
     """
     msize = _axis_size(mesh, "model")
     daxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -134,7 +136,16 @@ def cache_shardings(cfg: ModelConfig, cache_shape: Any, mesh: Mesh):
         names = _path_names(path)
         shape = tuple(x.shape)
         nd = len(shape)
-        if nd == 5:          # (L, B, T, Hkv, hd)
+        if (cfg.arch_kind != "hybrid" and names
+                and names[-1] in ("k", "v", "k_scale", "v_scale")):
+            spec = [None] * nd
+            spec[1] = dspec if shape[1] % dsize == 0 else None
+            if cfg.n_kv_heads % msize == 0:
+                spec[3] = "model"
+            elif shape[2] % msize == 0:
+                spec[2] = "model"
+            return NamedSharding(mesh, P(*spec))
+        if nd == 5:          # rwkv time-mix state (L, B, H, 64, 64)
             b = dspec if shape[1] % dsize == 0 else None
             if shape[3] % msize == 0:
                 return NamedSharding(mesh, P(None, b, None, "model", None))

@@ -1,8 +1,11 @@
 """Attention (GQA / RoPE / M-RoPE / sliding window / KV cache), MLPs, MoE.
 
 All layers are einsum-based so GSPMD can shard them; activations follow
-(batch, seq, ...) layout.  Decode paths take a KV cache and a scalar
-``cache_index`` and update in place with dynamic_update_slice.
+(batch, seq, ...) layout.  Decode paths take a KV cache and a fill
+cursor (``cache_index``, shared or per batch row) and write each row's
+new keys and values with one scatter per cache array; the uniform-layer
+decode step carries the whole stacked cache through its layer scan and
+donates it, so the scatter updates it in place.
 """
 from __future__ import annotations
 
@@ -129,23 +132,38 @@ def _sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 CHUNKED_ATTN_THRESHOLD = 8192
 
 
+def _cache_write(c: jax.Array, new: jax.Array, lead: Tuple[jax.Array, ...],
+                 pos: jax.Array) -> jax.Array:
+    """One scatter of ``new`` (B, S, ...) into ``c[*lead]`` at each batch
+    row's positions ``pos`` (B, S); trailing dims take ``c``'s form."""
+    B, S = pos.shape
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    upd = new.reshape(B, S, *c.shape[len(lead) + 2:]).astype(c.dtype)
+    return c.at[(*lead, rows, pos)].set(upd, unique_indices=True)
+
+
 def attention(p: Params, x: jax.Array, cfg: ModelConfig,
               positions: jax.Array,
               window: Optional[jax.Array] = None,
               causal: bool = True,
-              cache: Optional[Tuple[jax.Array, jax.Array]] = None,
+              cache: Optional[Tuple[jax.Array, ...]] = None,
               cache_index: Optional[jax.Array] = None,
+              cache_layer: Optional[jax.Array] = None,
               mrope_positions: Optional[jax.Array] = None,
               rope: bool = True,
-              ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
+              ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, ...]]]:
     """Self-attention; returns (output, updated cache).
 
     window: traced scalar; attend only to keys within `window` positions
-    (<=0 or None means unbounded).  cache: (k, v) of shape
-    (B, T_max, Hkv, hd); cache_index: first free slot — a scalar int32
-    when every batch row fills in lockstep, or a per-row (B,) int32
-    vector when rows advance independently (continuous batching: each
-    decode slot carries its own cursor).
+    (<=0 or None means unbounded).  cache: (k, v), or (k, v, k_scale,
+    v_scale) under ``kv_quant``.  With ``cache_layer`` (a traced layer
+    number) the arrays hold every layer, stacked as (L, B, T_max,
+    Hkv*hd) with scales (L, B, T_max, Hkv), and this layer reads and
+    writes ``[cache_layer]``; without it they hold one layer, (B, T_max,
+    Hkv, hd).  cache_index: first free position — a scalar int32 when
+    every batch row fills in lockstep, or a per-row (B,) int32 vector
+    when rows advance independently (continuous batching: each decode
+    slot carries its own cursor).
     """
     B, S, _ = x.shape
     if positions.ndim == 1:
@@ -163,55 +181,45 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
                 k = apply_rope(k, positions, cfg.rope_theta)
 
     if cache is not None:
-        # dynamic_update_slice wants every index in one dtype; under
-        # jax_enable_x64 the literal zeros would promote to int64 while
-        # cache_index stays int32, so pin them all to int32 explicitly.
-        cache_index = jnp.asarray(cache_index, jnp.int32)
-        zero = jnp.zeros((), jnp.int32)
-        per_slot = cache_index.ndim == 1
-        if per_slot:
-            # each batch row writes at its own cursor (vmapped update);
-            # the scalar path below broadcasts one write over all rows
-            def place(c, new):
-                return jax.vmap(
-                    lambda cb, nb, i: jax.lax.dynamic_update_slice(
-                        cb, nb, (i, zero, zero)))(c, new, cache_index)
-        else:
-            def place(c, new):
-                return jax.lax.dynamic_update_slice(
-                    c, new, (zero, cache_index, zero, zero))
+        # each row writes its S new keys from its own cursor; a shared
+        # cursor is the same write with every row's cursor equal
+        fill = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (B,))
+        pos = fill[:, None] + jnp.arange(S, dtype=jnp.int32)
+        lead = () if cache_layer is None else (cache_layer,)
+
+        def read(c, width):
+            """This layer's rows, heads split out: (B, T, Hkv, width)."""
+            c = c[lead]
+            return c.reshape(B, c.shape[1], cfg.n_kv_heads, width)
+
         with jax.named_scope("attn.kv_cache"):
             if cfg.kv_quant:
                 # int8 cache with per-vector scales: quantize the new
                 # slice, dequantize on read (fused on TPU; HBM moves
                 # 1B/elem not 2)
-                ck, cv, ks, vs = cache
                 k_s = (jnp.max(jnp.abs(k), axis=-1, keepdims=True) / 127.0
                        + 1e-8)
                 v_s = (jnp.max(jnp.abs(v), axis=-1, keepdims=True) / 127.0
                        + 1e-8)
-                k_q = jnp.round(k / k_s).astype(jnp.int8)
-                v_q = jnp.round(v / v_s).astype(jnp.int8)
-                ck = place(ck, k_q)
-                cv = place(cv, v_q)
-                ks = place(ks, k_s.astype(ks.dtype))
-                vs = place(vs, v_s.astype(vs.dtype))
-                k = ck.astype(x.dtype) * ks.astype(x.dtype)
-                v = cv.astype(x.dtype) * vs.astype(x.dtype)
-                new_cache = (ck, cv, ks, vs)
+                new = (jnp.round(k / k_s).astype(jnp.int8),
+                       jnp.round(v / v_s).astype(jnp.int8), k_s, v_s)
             else:
-                ck, cv = cache
-                ck = place(ck, k.astype(ck.dtype))
-                cv = place(cv, v.astype(cv.dtype))
-                k, v = ck, cv
-                new_cache = (ck, cv)
-        T = k.shape[1]
+                new = (k, v)
+            new_cache = tuple(_cache_write(c, n, lead, pos)
+                              for c, n in zip(cache, new))
         with jax.named_scope("attn.core"):
+            hd = q.shape[-1]
+            if cfg.kv_quant:
+                ck, cv, ks, vs = new_cache
+                k = read(ck, hd).astype(x.dtype) * read(ks, 1).astype(x.dtype)
+                v = read(cv, hd).astype(x.dtype) * read(vs, 1).astype(x.dtype)
+            else:
+                k, v = (read(c, hd) for c in new_cache)
+            T = k.shape[1]
             kpos = jnp.arange(T)[None, None, :]            # (1,1,T)
             qpos = positions[:, :, None]                   # (B,S,1)
             mask = kpos <= qpos                            # causal vs cache
-            fill = cache_index[:, None, None] if per_slot else cache_index
-            mask = mask & (kpos < (fill + S))
+            mask = mask & (kpos < (fill[:, None, None] + S))
             if window is not None:
                 mask = mask & (qpos - kpos < window)
     else:

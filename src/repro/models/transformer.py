@@ -129,8 +129,11 @@ def init_model(key: jax.Array, cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 def _uniform_layer(cfg: ModelConfig, x, layer_p, window, positions,
-                   mrope_positions=None, cache=None, cache_index=None):
-    """One pre-norm decoder layer; returns (x, new_cache, aux)."""
+                   mrope_positions=None, cache=None, cache_index=None,
+                   cache_layer=None):
+    """One pre-norm decoder layer; returns (x, new_cache, aux).  With
+    ``cache_layer`` the cache is every layer's, stacked (see
+    ``attention``), and comes back whole."""
     aux = jnp.zeros((), jnp.float32)
     x = hint(x, "batch", None, None)
     h = rms_norm(x, layer_p["ln1"], cfg.norm_eps)
@@ -153,6 +156,7 @@ def _uniform_layer(cfg: ModelConfig, x, layer_p, window, positions,
         c_in = None
     o, kv = attention(layer_p["attn"], h, cfg, positions, window=window,
                       cache=c_in, cache_index=cache_index,
+                      cache_layer=cache_layer,
                       mrope_positions=mrope_positions)
     x = x + o
     h2 = rms_norm(x, layer_p["ln2"], cfg.norm_eps)
@@ -305,21 +309,16 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                                        cfg.rglru_dim), dtype),
                     "h": jnp.zeros((batch, cfg.rglru_dim), jnp.float32)})
         return {"layers": caches}
+    # every layer stacked, each position's heads merged into one axis:
+    # the form the decode step's scatter, per-layer read and attention
+    # all take without a whole-cache relayout (or padding hd to a tile)
+    kv = (L, batch, max_len, cfg.n_kv_heads * hd)
     if cfg.kv_quant:
-        return {
-            "k": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, hd),
-                           jnp.int8),
-            "v": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, hd),
-                           jnp.int8),
-            "k_scale": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, 1),
-                                 dtype),
-            "v_scale": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, 1),
-                                 dtype),
-        }
-    return {
-        "k": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, hd), dtype),
-        "v": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, hd), dtype),
-    }
+        scale = (L, batch, max_len, cfg.n_kv_heads)
+        return {"k": jnp.zeros(kv, jnp.int8), "v": jnp.zeros(kv, jnp.int8),
+                "k_scale": jnp.zeros(scale, dtype),
+                "v_scale": jnp.zeros(scale, dtype)}
+    return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
 
 
 def zero_cache_slot(cfg: ModelConfig, cache: Params, slot: int) -> Params:
@@ -389,15 +388,21 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         x, ncache = jax.lax.scan(body, x, (params["layers"], cache))
         new_cache = ncache
     else:
+        # the stacked cache rides in the carry: each layer scatters its
+        # new keys into it and reads its own rows back, so with the cache
+        # donated the step updates it in place; scanned as xs/ys, every
+        # layer would be sliced out and a whole new cache stacked
         def body(carry, scanned):
-            x = carry
-            layer_p, window, c = scanned
-            x, nc, _ = _uniform_layer(cfg, x, layer_p, window, positions,
-                                      cache=c, cache_index=index)
-            return x, nc
+            x, c = carry
+            layer_p, window, l = scanned
+            x, c, _ = _uniform_layer(cfg, x, layer_p, window, positions,
+                                     cache=c, cache_index=index,
+                                     cache_layer=l)
+            return (x, c), None
 
-        x, ncache = jax.lax.scan(body, x, (params["layers"], windows, cache))
-        new_cache = ncache
+        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        (x, new_cache), _ = jax.lax.scan(
+            body, (x, cache), (params["layers"], windows, layer_ids))
 
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)

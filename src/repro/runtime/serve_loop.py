@@ -8,7 +8,9 @@ program (one jitted step, no shape polymorphism), which matches how the
 dry-run's serve_step is compiled.
 
 Per-slot state lives in plain arrays so the whole scheduler is
-host-driven; the device program is the single fused serve/prefill step.
+host-driven; the device program is the single fused serve/prefill step,
+which takes the KV cache donated: each tick replaces ``engine.cache``,
+and a reference to the cache from before a tick is invalid after it.
 
 Each tick is a ``serve.tick`` span (``runtime.telemetry``) holding, in
 order, ``serve.refill`` (with one ``serve.wipe`` per slot wiped),
@@ -65,6 +67,13 @@ def decode_graph(cfg: ModelConfig) -> Graph:
     return Graph(f"{cfg.name}-decode", ops)
 
 
+def jit_serve_step(cfg: ModelConfig):
+    """The engine's device program: the serve step, jitted with the cache
+    (argument 2) donated, so that each call updates the cache in place
+    and the cache passed in is invalid after it."""
+    return jax.jit(make_serve_step(cfg), donate_argnums=(2,))
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -104,7 +113,7 @@ class ServeEngine:
         # slots that have ever held a request: their cache rows must be
         # wiped before reuse so the next occupant can't attend to them
         self._slot_dirty = np.zeros(batch_slots, bool)
-        self._step = jax.jit(make_serve_step(cfg))
+        self._step = jit_serve_step(cfg)
         self.ticks = 0
         self.truncated = False
         # counters, reported by stats()

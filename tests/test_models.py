@@ -86,8 +86,9 @@ def test_smoke_serve_step(arch):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("arch", ["qwen2.5-3b", "gemma3-4b",
-                                  "recurrentgemma-2b", "rwkv6-1.6b"])
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "granite-moe-1b-a400m",
+                                  "gemma3-4b", "recurrentgemma-2b",
+                                  "rwkv6-1.6b"])
 def test_decode_matches_prefill(arch):
     """Greedy decode logits at step t == forward logits at position t."""
     cfg = get_config(arch, smoke=True)

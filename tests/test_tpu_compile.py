@@ -6,6 +6,8 @@ VMEM, programs that do not fit HBM.  The chip is described inside a
 module-scoped fixture, never at import: only one process at a time may
 load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -17,7 +19,7 @@ from repro.kernels.fused_mlp import fit_block
 from repro.kernels.ops import mlp_block
 from repro.models import init_model
 from repro.models.transformer import init_cache
-from repro.runtime.steps import make_serve_step
+from repro.runtime.serve_loop import jit_serve_step
 
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -48,22 +50,70 @@ def _on(sharding, tree):
         tree)
 
 
-def test_serve_decode_step_fits_one_chip(one_chip):
-    """ServeEngine's step at qwen2.5-3b's published widths, 4 slots of
+#: the served configurations, at their cells' 32 slots x 2048 positions
+SERVED = ["qwen2.5-3b", "granite-moe-1b-a400m"]
+#: while loops in the compiled step: the layer scan, and in the MoE layer
+#: the routing's searchsorted; none runs over the slots
+WHILE_LOOPS = {"qwen2.5-3b": 1, "granite-moe-1b-a400m": 2}
+
+
+@pytest.fixture(scope="module")
+def served_steps(one_chip):
+    """ServeEngine's jitted step for each served configuration, compiled
+    for one v5e, with the bytes of its cache."""
+    out = {}
+    for arch in SERVED:
+        cfg = get_config(arch)
+        params = _on(one_chip, jax.eval_shape(
+            lambda: init_model(jax.random.PRNGKey(0), cfg)))
+        cache = _on(one_chip,
+                    jax.eval_shape(lambda: init_cache(cfg, 32, 2048)))
+        tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+        index = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+        compiled = jit_serve_step(cfg).lower(
+            params, tokens, cache, index).compile()
+        cache_bytes = sum(a.size * a.dtype.itemsize
+                          for a in jax.tree.leaves(cache))
+        out[arch] = compiled, cache_bytes
+    return out
+
+
+def test_serve_decode_step_fits_one_chip(served_steps):
+    """ServeEngine's step at qwen2.5-3b's published widths, 32 slots of
     2048 tokens, compiles for one v5e and fits its 16 GB."""
-    cfg = get_config("qwen2.5-3b")
-    params = _on(one_chip, jax.eval_shape(
-        lambda: init_model(jax.random.PRNGKey(0), cfg)))
-    cache = _on(one_chip, jax.eval_shape(lambda: init_cache(cfg, 4, 2048)))
-    tokens = jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=one_chip)
-    index = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(make_serve_step(cfg)).lower(
-        params, tokens, cache, index).compile()
+    compiled, _ = served_steps["qwen2.5-3b"]
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 6 * 10**9     # the bf16 weights
     assert total < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_serve_step_updates_cache_in_place(served_steps, arch):
+    """The donated cache is the output's buffer, and no temporary near
+    its size stands in for it."""
+    compiled, cache_bytes = served_steps[arch]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
+    assert mem.temp_size_in_bytes < 0.3 * 10**9, mem
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_serve_step_keeps_cache_unpadded(served_steps, arch):
+    """The outputs are the cache at init_cache's bytes and the sampled
+    tokens: a head size padded to the tile would double the cache."""
+    compiled, cache_bytes = served_steps[arch]
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert cache_bytes <= out < cache_bytes + 2**20, (out, cache_bytes)
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_serve_step_has_no_loop_over_slots(served_steps, arch):
+    """Each slot's write is one scatter, not a loop over the slots."""
+    compiled, _ = served_steps[arch]
+    loops = re.findall(r"= \S.* while\(", compiled.as_text())
+    assert len(loops) == WHILE_LOOPS[arch], loops
 
 
 @pytest.mark.parametrize("tokens", [37, 300])
