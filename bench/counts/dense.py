@@ -25,15 +25,17 @@ def _ffn_params(c: dict) -> int:
 
 
 def params(c: dict) -> int:
-    """Parameters of the served tree, padded embedding rows included."""
+    """Parameters of the served tree, padded embedding rows (and an
+    untied head's padded columns) included."""
     d, L = c["hidden_size"], c["num_hidden_layers"]
     per_layer = _attn_params(c) + _ffn_params(c) + 2 * d
-    return L * per_layer + padded_vocab(c) * d + d
+    heads = 1 if c["tie_word_embeddings"] else 2
+    return L * per_layer + heads * padded_vocab(c) * d + d
 
 
 def matmul_params_per_token(c: dict) -> int:
-    """Weights one token multiplies through: every layer and the tied
-    LM head over the real vocabulary."""
+    """Weights one token multiplies through: every layer and the LM head
+    (tied or not) over the real vocabulary."""
     return (c["num_hidden_layers"] * (_attn_params(c) + _ffn_params(c))
             + c["vocab_size"] * c["hidden_size"])
 

@@ -13,10 +13,26 @@ positions.
 A control runs the reference in a lower precision in the program's
 place (``mode`` ``fp8w`` or ``fp8``) and reads, at the same positions,
 the gap of the token the control puts first.
+
+The reference module gives ``prepare(leaves, mode)``, ``matmul(a, w,
+mode)``, ``rms_norm(x, s, eps)`` and ``layer(p, x, c, mode)``, where
+``p`` holds one layer's leaves keyed by their whole path
+(``<stack>/attn/wq``).  Layers run in the order ``order(c)`` gives, a
+list of (stack, index within the stack) from the first layer to the
+last, where the module has it; else every stack of the layout
+(``weights.stacks``) in turn: for the one default stack, ``layers`` 0
+to ``num_hidden_layers - 1``.  A ``layer`` that takes an ``index`` argument gets the
+layer's place in the whole model as a traced int32 scalar (one
+compiled layer a stack serves every index), for a layer that depends on
+where it stands: a window on some layers and not others.  The output
+head is the layout's ``unembed`` leaf, (d, padded vocabulary) cut to
+``vocab_size``, where it has one, else the tied ``embed``; the fp8
+controls round it over its input axis, as every other weight.
 """
 from __future__ import annotations
 
 import importlib
+import inspect
 from typing import List, Sequence, Tuple
 
 import jax
@@ -45,7 +61,7 @@ def sequences(reqs: Sequence) -> List[Tuple[List[int], List[int]]]:
 
 
 def _layout_and_reference(c: dict):
-    lay = importlib.import_module(f"bench.weights.{c['arch_kind']}").layout(c)
+    lay = weights.layout_module(c).layout(c)
     ref = importlib.import_module(f"bench.reference.{c['arch_kind']}")
     return lay, ref
 
@@ -86,22 +102,36 @@ def _head_fn(mode: str, matmul):
     return jax.jit(head)
 
 
+def _order(c: dict, ref) -> List[Tuple[str, int]]:
+    """(stack, index in the stack) of every layer, first to last."""
+    if hasattr(ref, "order"):
+        return [(s, int(i)) for s, i in ref.order(c)]
+    return [(s, i) for s, n in weights.stacks(c).items() for i in range(n)]
+
+
 def _hidden(c, lay, ref, seed, fed, mode):
-    """Final-normed hidden states of every group, layer by layer."""
+    """Final-normed hidden states of every group, layer by layer, and the
+    output head as rows, (vocab_size, d)."""
     lo, hi = weights.seed_words(seed)
     glob = ref.prepare(weights.make_globals(lay)(lo, hi), mode)
-    layer_w = weights.make_layer(lay)
+    order = _order(c, ref)
+    layer_w = {s: weights.make_layer(lay, s) for s in dict(order)}
     prep = jax.jit(lambda leaves: ref.prepare(leaves, mode))
-    step = jax.jit(lambda p, x: ref.layer(p, x, c, mode))
-    emb = glob["embed"][:c["vocab_size"]]
+    if "index" in inspect.signature(ref.layer).parameters:
+        step = jax.jit(lambda p, x, i: ref.layer(p, x, c, mode, index=i))
+    else:
+        step = jax.jit(lambda p, x, i: ref.layer(p, x, c, mode))
+    V = c["vocab_size"]
+    emb = glob["embed"][:V]
+    head = glob["unembed"][:, :V].T if "unembed" in glob else emb
     xs = [emb[jnp.asarray(fed[i:i + GROUP])]
           for i in range(0, len(fed), GROUP)]
-    for layer in range(c["num_hidden_layers"]):
-        p = prep(layer_w(lo, hi, np.uint32(layer)))
-        xs = [step(p, x) for x in xs]
+    for index, (stack, l) in enumerate(order):
+        p = prep(layer_w[stack](lo, hi, np.uint32(l)))
+        xs = [step(p, x, np.int32(index)) for x in xs]
     eps = c["rms_norm_eps"]
     xs = [ref.rms_norm(x, glob["ln_f"], eps) for x in xs]
-    return xs, emb
+    return xs, head
 
 
 def logit_gaps(c: dict, seed: int, seqs, modes=("f32",)) -> dict:
@@ -116,22 +146,23 @@ def logit_gaps(c: dict, seed: int, seqs, modes=("f32",)) -> dict:
     fed, served, mask = _pack(seqs, T)
     out = {}
     with jax.default_matmul_precision("highest"):
-        xs_ref, emb = _hidden(c, lay, ref, seed, fed, "f32")
+        xs_ref, head_w = _hidden(c, lay, ref, seed, fed, "f32")
         head = _head_fn("f32", ref.matmul)
         for mode in modes:
             if mode == "f32":
                 nxt = served
             else:
-                xs_c, emb_c = _hidden(c, lay, ref, seed, fed, mode)
+                xs_c, head_wc = _hidden(c, lay, ref, seed, fed, mode)
                 head_c = _head_fn(mode, ref.matmul)
                 nxt = np.concatenate([
-                    np.asarray(head_c(x, emb_c, jnp.zeros(x.shape[:2],
-                                                          jnp.int32))[2])
+                    np.asarray(head_c(x, head_wc, jnp.zeros(x.shape[:2],
+                                                            jnp.int32))[2])
                     for x in xs_c])
-                del xs_c, emb_c
+                del xs_c, head_wc
             gap = np.concatenate([
                 np.asarray(b - a) for b, a, _ in (
-                    head(x, emb, jnp.asarray(nxt[i * GROUP:(i + 1) * GROUP]))
+                    head(x, head_w,
+                         jnp.asarray(nxt[i * GROUP:(i + 1) * GROUP]))
                     for i, x in enumerate(xs_ref))])
             g = gap[mask]
             out[mode] = {"max_gap": float(g.max()) if g.size else 0.0,

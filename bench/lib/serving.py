@@ -20,6 +20,14 @@ import numpy as np
 from bench.lib import weights
 
 
+def program_keys(c: dict) -> dict:
+    """Program-config fields beyond the GQA ones that the file's keys fix
+    (a latent rank, a count of shared experts): {field: value} from the
+    layout module's ``program_keys(c)``, where it has one."""
+    mod = weights.layout_module(c)
+    return dict(mod.program_keys(c)) if hasattr(mod, "program_keys") else {}
+
+
 def build(c: dict, layout: dict, seed: int, phases: Optional[dict] = None):
     """The program's engine on weights made in one jitted call.  Seconds
     of each phase of set-up go into ``phases`` where given."""
@@ -43,8 +51,9 @@ def build(c: dict, layout: dict, seed: int, phases: Optional[dict] = None):
               "tie_embeddings": c["tie_word_embeddings"],
               "qkv_bias": c["attention_bias"],
               "n_experts": c.get("num_local_experts", 0),
-              "top_k": c.get("num_experts_per_tok", 0)}
-    got = {k: getattr(cfg, k) for k in expect}
+              "top_k": c.get("num_experts_per_tok", 0),
+              **program_keys(c)}
+    got = {k: getattr(cfg, k, None) for k in expect}
     if got != expect:
         raise SystemExit(f"program config {c['arch']} is not the file's: "
                          f"{got} != {expect}")
@@ -52,7 +61,7 @@ def build(c: dict, layout: dict, seed: int, phases: Optional[dict] = None):
         lambda: init_model(jax.random.PRNGKey(0), cfg)))
     lo, hi = weights.seed_words(seed)
     params = jax.block_until_ready(
-        weights.make_tree(layout, c["num_hidden_layers"])(lo, hi))
+        weights.make_tree(layout, weights.stacks(c))(lo, hi))
     mark("weights")
     if weights.tree_signature(params) != want:
         raise SystemExit("the program's parameter tree has changed: "

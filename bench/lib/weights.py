@@ -7,13 +7,23 @@ integer from ``jax.random.bits`` times a power of two, cast to the leaf's
 dtype: integer arithmetic, an exact scaling and one rounding cast, so the
 program's tree (built in one jitted call) and the reference's layer by
 layer (built again from the seed) hold the same numbers.
+
+Layer stacks: a stacked leaf belongs to the stack named by the first
+part of its path, and is built over that stack's length.  The layout
+module may give ``stacks(c) -> {name: length}``, in the order the
+layers run (a leading dense stack before the expert stack, say);
+without it there is one stack, ``layers``, over ``num_hidden_layers``.
+Layer ``l`` of a stack draws its values from the leaf's key folded with
+``l``, its index within that stack, so a stack's layers do not depend
+on the other stacks.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import math
 import zlib
-from typing import Dict, Tuple
+from typing import Dict, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -65,9 +75,29 @@ def _nest(flat: Dict[Path, jax.Array]) -> dict:
     return out
 
 
-def make_tree(layout: Dict[Path, Leaf], n_layers: int):
+def layout_module(c: dict):
+    """``bench/weights/<arch_kind>.py`` of a configuration."""
+    return importlib.import_module(f"bench.weights.{c['arch_kind']}")
+
+
+def stacks(c: dict) -> Dict[str, int]:
+    """The layer stacks of a configuration's layout, {name: length}, in
+    the order the layers run: the layout module's ``stacks(c)`` where it
+    has one, else ``layers`` over ``num_hidden_layers``."""
+    mod = layout_module(c)
+    if hasattr(mod, "stacks"):
+        return dict(mod.stacks(c))
+    return {"layers": c["num_hidden_layers"]}
+
+
+def make_tree(layout: Dict[Path, Leaf], stack_lengths: Union[int, dict]):
     """jitted (lo, hi) -> the whole parameter tree, stacked leaves built
-    one layer at a time so no leaf's random bits are held whole."""
+    one layer at a time so no leaf's random bits are held whole.
+    ``stack_lengths``: {stack: length} (``stacks``), or one number, the
+    length of the one stack ``layers``."""
+    if isinstance(stack_lengths, int):
+        stack_lengths = {"layers": stack_lengths}
+
     def build(lo, hi):
         base = _base_key(lo, hi)
         flat = {}
@@ -77,25 +107,27 @@ def make_tree(layout: Dict[Path, Leaf], n_layers: int):
                 flat[path] = jax.lax.map(
                     lambda l, key=key, leaf=leaf: _values(
                         jax.random.fold_in(key, l), leaf),
-                    jnp.arange(n_layers, dtype=jnp.uint32))
+                    jnp.arange(stack_lengths[path[0]], dtype=jnp.uint32))
             else:
                 flat[path] = _values(key, leaf)
         return _nest(flat)
     return jax.jit(build)
 
 
-def make_layer(layout: Dict[Path, Leaf]):
-    """jitted (lo, hi, l) -> the stacked leaves of layer ``l`` (flat)."""
+def make_layer(layout: Dict[Path, Leaf], stack: str = "layers"):
+    """jitted (lo, hi, l) -> the leaves of layer ``l`` of ``stack`` (flat,
+    keyed by their whole path: ``<stack>/attn/wq``)."""
     def build(lo, hi, l):
         base = _base_key(lo, hi)
         return {"/".join(path): _values(
                     jax.random.fold_in(_leaf_key(base, path), l), leaf)
-                for path, leaf in layout.items() if leaf.stacked}
+                for path, leaf in layout.items()
+                if leaf.stacked and path[0] == stack}
     return jax.jit(build)
 
 
 def make_globals(layout: Dict[Path, Leaf]):
-    """jitted (lo, hi) -> the leaves outside the layer stack (flat)."""
+    """jitted (lo, hi) -> the leaves outside the layer stacks (flat)."""
     def build(lo, hi):
         base = _base_key(lo, hi)
         return {"/".join(path): _values(_leaf_key(base, path), leaf)

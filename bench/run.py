@@ -15,6 +15,21 @@ as ``<metric>.<suffix>`` is read by the first of ``<metric>.<suffix>.py``
 and ``<metric>.py`` that exists.  Adding a cell, a mix, a kind or a
 metric adds files; nothing here names one.
 
+A served configuration adds ``bench/configs/<config>.json`` (the
+source's keys; ``arch`` names the program's config, ``arch_kind`` the
+files below) and, for an ``arch_kind`` the benchmark has not got:
+``bench/weights/<arch_kind>.py`` (``layout(c)``, every leaf of the
+program's tree; optionally ``stacks(c)``, layer stacks of their own
+lengths in the order they run, and ``program_keys(c)``, program-config
+fields the file fixes beyond the GQA ones ``lib/serving.py`` compares),
+``bench/reference/<arch_kind>.py`` (the plain float32 reference:
+``prepare``, ``matmul``, ``rms_norm`` and ``layer``, which may take the
+layer's ``index`` in the whole model; optionally ``order(c)``; see
+``lib/check.py``) and ``bench/counts/<arch_kind>.py`` (a decode step's
+FLOPs and bytes); each of its cells adds ``bench/limits/<cell>.json``,
+and a new mix ``bench/traffic/<mix>.json``.  An untied output head is
+an ``unembed`` leaf in the layout.
+
 A kind module has ``STEP`` and four functions:
 ``build(c, mix, seed, devices, phases)`` returns the system, with every
 program it runs compiled and warmed up (seconds of each phase go into

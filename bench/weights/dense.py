@@ -2,9 +2,9 @@
 
 Paths, shapes and dtypes are those the program's ``init_model`` gives
 (``run.py`` checks them against its ``eval_shape`` before every run).
-Standard deviations: 1/sqrt(fan-in) for projections, 0.02 for the tied
-embedding, and small non-zero norm scales and biases so that the
-comparison with the reference covers them too.
+Standard deviations: 1/sqrt(fan-in) for projections and an untied
+output head, 0.02 for the embedding, and small non-zero norm scales and
+biases so that the comparison with the reference covers them too.
 """
 from __future__ import annotations
 
@@ -38,9 +38,14 @@ def attention_leaves(c: dict) -> Dict[Path, Leaf]:
 
 
 def global_leaves(c: dict) -> Dict[Path, Leaf]:
-    d = c["hidden_size"]
-    return {("embed",): Leaf((padded_vocab(c), d), c["torch_dtype"], 0.02),
-            ("ln_f",): Leaf((d,), "float32", 0.05)}
+    """The embedding, the final norm and, where the file unties them, the
+    output head (d, padded vocabulary), as the program stores it."""
+    d, dt = c["hidden_size"], c["torch_dtype"]
+    out = {("embed",): Leaf((padded_vocab(c), d), dt, 0.02),
+           ("ln_f",): Leaf((d,), "float32", 0.05)}
+    if not c["tie_word_embeddings"]:
+        out[("unembed",)] = Leaf((d, padded_vocab(c)), dt, d ** -0.5)
+    return out
 
 
 def layout(c: dict) -> Dict[Path, Leaf]:

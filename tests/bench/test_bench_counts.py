@@ -37,6 +37,21 @@ def test_parameter_counts_match_the_program(name, counts):
         x.size for x in jax.tree.leaves(shapes))
 
 
+def test_untied_head_is_counted_like_the_programs():
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import init_model
+    c = json.loads((ROOT / "tests/bench/fixtures/tiny-untied.json")
+                   .read_text())
+    prog = dataclasses.replace(get_config(c["arch"], smoke=True),
+                               tie_embeddings=False)
+    shapes = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), prog))
+    assert dense.params(c) == sum(x.size for x in jax.tree.leaves(shapes))
+    # the head is one matmul over the real vocabulary, tied or not
+    assert dense.decode_flops(c, 1, 0) == dense.decode_flops(
+        {**c, "tie_word_embeddings": True}, 1, 0)
+
+
 def test_flops_per_token_by_hand():
     # one token, no keys: 2 x (36 x (9,439,744 + 67,633,152)
     #                          + 151,936 x 2048)

@@ -190,3 +190,71 @@ def test_finds_a_new_kind_by_name(tmp_path):
     assert res["attempted"] > 0
     assert any(line.startswith("steps in the window:")
                for line in p.stdout.splitlines())
+
+
+# in a copy: greedy tokens from the stacked fixture's own reference, then
+# the check of ``correct`` on them, and on them with the index ignored
+STACKED_RUN = """
+import json, sys
+sys.path[:0] = ['.']
+import jax
+import numpy as np
+from bench.lib import check, weights
+
+c = json.load(open('bench/configs/tiny-stacked.json'))
+seed = 2**31 + 11
+lay, ref = check._layout_and_reference(c)
+tree = weights.make_tree(lay, weights.stacks(c))(*weights.seed_words(seed))
+rng = np.random.default_rng(seed)
+seqs = [(rng.integers(0, c['vocab_size'], n).tolist(), []) for n in (30, 9, 41)]
+with jax.default_matmul_precision('highest'):
+    head = check._head_fn('f32', ref.matmul)
+    for _ in range(8):
+        fed, _, _ = check._pack([(p, o + [0]) for p, o in seqs],
+                                c['serve']['max_len'])
+        xs, w = check._hidden(c, lay, ref, seed, fed, 'f32')
+        arg = np.concatenate([np.asarray(head(x, w, np.zeros(x.shape[:2],
+                                                             np.int32))[2])
+                              for x in xs])
+        for i, (p, o) in enumerate(seqs):
+            o.append(int(arg[i, len(p) + len(o) - 1]))
+gaps = check.logit_gaps(c, seed, seqs)['f32']
+layer = ref.layer
+ref.layer = lambda p, x, c, mode: layer(p, x, c, mode, index=0)
+blind = check.logit_gaps(c, seed, seqs)['f32']
+print(json.dumps({
+    'lengths': {k: v.shape[0] for k, v in
+                [('dense_layers', tree['dense_layers']['ln1']),
+                 ('layers', tree['layers']['ln1'])]},
+    'unembed': list(tree['unembed'].shape),
+    'order': check._order(c, ref), 'gaps': gaps, 'blind': blind}))
+"""
+
+
+def test_finds_a_stacked_untied_kind_by_name(tmp_path):
+    """A configuration whose layers differ (a 1-layer dense stack before a
+    3-layer expert stack, a window on every other layer by its index, an
+    untied head) arrives as files only: a layout, a reference and a
+    configuration; no file under ``bench/lib`` is edited."""
+    copy = _bench_only_copy(tmp_path)
+    fix = ROOT / "tests/bench/fixtures"
+    shutil.copy(fix / "weights_stacked.py", copy / "bench/weights/stacked.py")
+    shutil.copy(fix / "reference_layered.py",
+                copy / "bench/reference/stacked.py")
+    shutil.copy(fix / "tiny-stacked.json",
+                copy / "bench/configs/tiny-stacked.json")
+    p = subprocess.run([sys.executable, "-c", STACKED_RUN], cwd=copy,
+                       env=_env(), capture_output=True, text=True,
+                       timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    res = json.loads(p.stdout.splitlines()[-1])
+    assert res["lengths"] == {"dense_layers": 1, "layers": 3}
+    assert res["unembed"] == [64, 512]
+    assert res["order"] == [["dense_layers", 0], ["layers", 0],
+                            ["layers", 1], ["layers", 2]]
+    assert res["gaps"]["positions"] == 3 * 8
+    assert res["gaps"]["max_gap"] == 0.0, res["gaps"]
+    # the index ignored (every layer local) reads 2.78 on the CPU
+    assert res["blind"]["max_gap"] > 1.0, res["blind"]
+    for f in (ROOT / "bench/lib").glob("*.py"):
+        assert (copy / "bench/lib" / f.name).read_bytes() == f.read_bytes()
