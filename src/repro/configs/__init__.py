@@ -24,7 +24,7 @@ ARCHS = {
     "rwkv6-1.6b": "rwkv6_1_6b",
     "whisper-medium": "whisper_medium",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "qwen2-vl-2b": "qwen2_vl_2b",
 }
 

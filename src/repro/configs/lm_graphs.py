@@ -125,22 +125,32 @@ def _moe_mlp(w: _Wire, cfg: ModelConfig, tag: str, x: str,
     experts fork from ``x`` and join at the weighted combine — one wide
     series-parallel region per layer (the dominant fold win: unfolded,
     the planner re-prices this region's whole org x staging enumeration
-    for every layer)."""
+    for every layer).  Shared experts are one more branch, as wide as
+    all of them together."""
+    ff = cfg.expert_ff
     router = w.emit(gemm(f"{tag}.router", tokens, cfg.n_experts,
                          cfg.d_model, inputs=(x,)))
     tails = [router]
     for e in range(cfg.top_k):
-        up = w.emit(gemm(f"{tag}.e{e}.up", tokens, cfg.d_ff, cfg.d_model,
+        up = w.emit(gemm(f"{tag}.e{e}.up", tokens, ff, cfg.d_model,
                          inputs=(x,)))
         tails.append(w.emit(gemm(f"{tag}.e{e}.down", tokens, cfg.d_model,
-                                 cfg.d_ff, inputs=(up,))))
+                                 ff, inputs=(up,))))
+    if cfg.n_shared_experts:
+        width = cfg.n_shared_experts * ff
+        up = w.emit(gemm(f"{tag}.shared.up", tokens, width, cfg.d_model,
+                         inputs=(x,)))
+        tails.append(w.emit(gemm(f"{tag}.shared.down", tokens, cfg.d_model,
+                                 width, inputs=(up,))))
     return w.emit(add(f"{tag}.combine", tokens, 1, 1, cfg.d_model,
                       inputs=tuple(tails)))
 
 
 def _block(w: _Wire, cfg: ModelConfig, tag: str, x: str, tokens: int,
-           mixer: str, span: int, kv_streams: Optional[int] = None) -> str:
-    """One transformer block: token mixer + residual, FFN + residual."""
+           mixer: str, span: int, kv_streams: Optional[int] = None,
+           dense: bool = False) -> str:
+    """One transformer block: token mixer + residual, FFN + residual
+    (``dense``: a leading layer's gated MLP in place of the experts)."""
     if mixer == "attn":
         mixed = _attention(w, cfg, f"{tag}.attn", x, tokens, span,
                            kv_streams=kv_streams)
@@ -156,7 +166,7 @@ def _block(w: _Wire, cfg: ModelConfig, tag: str, x: str, tokens: int,
         raise ValueError(mixer)
     r1 = w.emit(add(f"{tag}.r1", tokens, 1, 1, cfg.d_model,
                     inputs=(mixed, x)))
-    if cfg.arch_kind == "moe":
+    if cfg.arch_kind == "moe" and not dense:
         ff = _moe_mlp(w, cfg, f"{tag}.moe", r1, tokens)
     elif cfg.arch_kind in ("encdec", "rwkv"):
         ff = _plain_mlp(w, cfg, f"{tag}.mlp", r1, tokens)
@@ -185,7 +195,8 @@ def decode_graph(cfg: ModelConfig, batch: int = DECODE_BATCH,
         tag = f"l{layer}"
         mixer = _layer_mixer(cfg, layer)
         span = _mixer_span(cfg, layer, context) if mixer == "attn" else 1
-        x = _block(w, cfg, tag, x, batch, mixer, span)
+        x = _block(w, cfg, tag, x, batch, mixer, span,
+                   dense=layer < cfg.n_dense_layers)
         if cfg.arch_kind == "encdec":
             # decoder-only serve step: every layer also cross-attends the
             # encoder output (fixed enc_frames keys, one shared stream)
@@ -217,7 +228,8 @@ def prefill_graph(cfg: ModelConfig, batch: int = PREFILL_BATCH,
         mixer = _layer_mixer(cfg, layer)
         span = _mixer_span(cfg, layer, context) if mixer == "attn" else 1
         x = _block(w, cfg, f"l{layer}", x, tokens, mixer, span,
-                   kv_streams=batch if mixer == "attn" else None)
+                   kv_streams=batch if mixer == "attn" else None,
+                   dense=layer < cfg.n_dense_layers)
     return Graph(name, w.ops)
 
 

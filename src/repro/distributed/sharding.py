@@ -10,7 +10,8 @@ mesh (+ an outer "pod" axis as extra data parallelism):
   * MoE expert tensors: expert dim over "model" (EP);
   * activations: batch over ("pod","data");
   * KV caches: batch over "data", whole kv-heads over "model" when
-    divisible, otherwise sequence over "model" (cache sequence-parallelism).
+    divisible, otherwise sequence over "model" (cache sequence-parallelism);
+    a latent cache (MLA) is shared by the heads: batch over "data" only.
 """
 from __future__ import annotations
 
@@ -25,7 +26,7 @@ from repro.models.common import ModelConfig
 #: leaf names whose LAST dim is the parallel (TP) dim
 _LAST_MODEL = {
     "wq", "wk", "wv", "w_gate", "w_up", "w_in", "w_x", "w_y",
-    "w_r", "w_g", "w_decay", "w_k", "patch_proj", "unembed",
+    "w_r", "w_g", "w_decay", "w_k", "patch_proj", "unembed", "wkv_b",
 }
 #: leaf names whose FIRST (non-stacked) dim is the parallel dim
 _FIRST_MODEL = {"wo", "w_down", "w_out", "w_v", "w_o"}
@@ -78,7 +79,8 @@ def param_spec(path: Tuple[str, ...], shape: Tuple[int, ...],
 
 
 def _is_scanned(cfg: ModelConfig, path: Tuple[str, ...]) -> bool:
-    return any(p in ("layers", "enc_layers", "dec_layers") for p in path) \
+    return any(p in ("layers", "dense_layers", "enc_layers", "dec_layers")
+               for p in path) \
         and cfg.arch_kind != "hybrid"
 
 
@@ -125,7 +127,9 @@ def cache_shardings(cfg: ModelConfig, cache_shape: Any, mesh: Mesh):
     (L, B, T, Hkv, hd); other families, per-arch states).  Batch over
     "data"; axis 3 over "model" when the kv-heads divide it, so each
     shard holds whole heads, else the sequence dim (cache sequence
-    parallelism — essential for GQA with few kv heads).
+    parallelism — essential for GQA with few kv heads).  The latent
+    cache, (L, B, T, rank) and (L, B, rope, T), has no head axis: batch
+    over "data", replicated over "model".
     """
     msize = _axis_size(mesh, "model")
     daxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -136,6 +140,10 @@ def cache_shardings(cfg: ModelConfig, cache_shape: Any, mesh: Mesh):
         names = _path_names(path)
         shape = tuple(x.shape)
         nd = len(shape)
+        if names and names[-1] in ("c_kv", "k_pe"):
+            spec = [None] * nd
+            spec[1] = dspec if shape[1] % dsize == 0 else None
+            return NamedSharding(mesh, P(*spec))
         if (cfg.arch_kind != "hybrid" and names
                 and names[-1] in ("k", "v", "k_scale", "v_scale")):
             spec = [None] * nd

@@ -33,9 +33,22 @@ class ModelConfig:
     local_window: int = 0
     global_every: int = 0
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0               # routed experts the router scores
     top_k: int = 0
     capacity_factor: float = 1.25
+    moe_d_ff: int = 0                # expert width; 0 means d_ff
+    n_shared_experts: int = 0        # SwiGLU of n_shared * moe width, every token
+    n_dense_layers: int = 0          # leading layers with a dense MLP of d_ff
+    router: str = "softmax"          # softmax | sigmoid (+ correction bias)
+    routed_scaling: float = 1.0      # gate multiplier after renormalising
+    # expert parallelism: a layer is divided over ep_size chips, and this
+    # one holds n_experts // ep_size of its experts (routing is over all)
+    ep_size: int = 1
+    # latent attention (MLA, DeepSeek-V2/V3); kv_lora_rank 0 means GQA
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    qk_nope_dim: int = 0
+    v_head_dim: int = 0
     # hybrid (recurrentgemma): pattern unit, e.g. ("rglru","rglru","attn")
     block_pattern: Tuple[str, ...] = ()
     rglru_dim: int = 0               # recurrence width (lru_width)
@@ -65,28 +78,52 @@ class ModelConfig:
         """Embedding rows padded to a TP-shardable multiple (256)."""
         return -(-self.vocab // 256) * 256
 
-    def n_params(self) -> int:
-        """Approximate parameter count (for roofline MODEL_FLOPS)."""
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts this chip holds of each expert layer."""
+        return self.n_experts // self.ep_size
+
+    def _attn_params(self) -> int:
+        d, H = self.d_model, self.n_heads
+        if self.kv_lora_rank:
+            r, rope = self.kv_lora_rank, self.qk_rope_dim
+            return (d * H * (self.qk_nope_dim + rope) + d * (r + rope) + r
+                    + r * H * (self.qk_nope_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
         hd = self.hd
-        attn = self.d_model * hd * (self.n_heads + 2 * self.n_kv_heads) \
-            + self.n_heads * hd * self.d_model
+        return d * hd * (H + 2 * self.n_kv_heads) + H * hd * d
+
+    def _params(self, routed: int) -> int:
+        """``routed`` parameters of routed experts in each expert layer
+        (routers left out), its shared experts, leading dense layers, and
+        the embedding (and an untied head)."""
+        d = self.d_model
+        n_moe = self.n_layers - self.n_dense_layers
         if self.n_experts:
-            mlp = 3 * self.d_model * self.d_ff * self.n_experts
+            mlp = routed + 3 * d * self.expert_ff * self.n_shared_experts
         else:
-            mlp = 3 * self.d_model * self.d_ff
-        per_layer = attn + mlp
-        emb = self.vocab * self.d_model * (1 if self.tie_embeddings else 2)
-        return self.n_layers * per_layer + emb
+            mlp = 3 * d * self.d_ff
+        dense = self.n_dense_layers * (self._attn_params() + 3 * d * self.d_ff)
+        emb = self.vocab * d * (1 if self.tie_embeddings else 2)
+        return n_moe * (self._attn_params() + mlp) + dense + emb
+
+    def n_params(self) -> int:
+        """Approximate parameter count held on this chip (for roofline
+        MODEL_FLOPS): every routed expert it holds."""
+        return self._params(3 * self.d_model * self.expert_ff
+                            * self.experts_held)
 
     def n_active_params(self) -> int:
+        """Parameters one token multiplies through on this chip: its
+        ``top_k`` routed experts, of which a 1/ep_size share lies here."""
         if not self.n_experts:
             return self.n_params()
-        hd = self.hd
-        attn = self.d_model * hd * (self.n_heads + 2 * self.n_kv_heads) \
-            + self.n_heads * hd * self.d_model
-        mlp = 3 * self.d_model * self.d_ff * self.top_k
-        emb = self.vocab * self.d_model * (1 if self.tie_embeddings else 2)
-        return self.n_layers * (attn + mlp) + emb
+        return self._params(3 * self.d_model * self.expert_ff * self.top_k
+                            // self.ep_size)
 
 
 @dataclasses.dataclass(frozen=True)

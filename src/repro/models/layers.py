@@ -1,4 +1,6 @@
-"""Attention (GQA / RoPE / M-RoPE / sliding window / KV cache), MLPs, MoE.
+"""Attention (GQA / RoPE / M-RoPE / sliding window / KV cache), latent
+attention (MLA) over a latent cache, MLPs, MoE (softmax or sigmoid
+routing, shared experts, a chip's share of the experts).
 
 All layers are einsum-based so GSPMD can shard them; activations follow
 (batch, seq, ...) layout.  Decode paths take a KV cache and a fill
@@ -9,6 +11,7 @@ donates it, so the scatter updates it in place.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -271,6 +274,103 @@ def cross_attention(p: Params, x: jax.Array, enc: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+
+def init_mla(key: jax.Array, cfg: ModelConfig) -> Params:
+    """Queries from ``wq``; one latent per position from ``wkv_a`` (its
+    first ``kv_lora_rank`` columns, normed by ``kv_norm``) with a rotary
+    key part shared by the heads (the last ``qk_rope_dim``); ``wkv_b``
+    expands the latent to each head's [k_nope | v]."""
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, v = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, H * (nope + rope)), cfg.dtype),
+        "wkv_a": dense_init(ks[1], (d, r + rope), cfg.dtype),
+        "kv_norm": jnp.zeros((r,), jnp.float32),
+        "wkv_b": dense_init(ks[2], (r, H * (nope + v)), cfg.dtype),
+        "wo": dense_init(ks[3], (H * v, d), cfg.dtype),
+    }
+
+
+def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
+                  positions: jax.Array,
+                  cache: Optional[Tuple[jax.Array, jax.Array]] = None,
+                  cache_index: Optional[jax.Array] = None,
+                  cache_layer: Optional[jax.Array] = None,
+                  ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, ...]]]:
+    """Causal latent attention; returns (output, updated cache).
+
+    Without a cache the latent is expanded to per-head keys and values
+    over the sequence (the published form).  With one the step is
+    absorbed: the cache holds each position's normed latent, (L, B,
+    T_max, kv_lora_rank), and its roped key part stored position-minor,
+    (L, B, qk_rope_dim, T_max), so no axis of the cache is padded to a
+    tile; each head's query is taken into latent space through its
+    slice of ``wkv_b`` and scores and values are read off the latents,
+    which are never expanded over the cache.  ``cache_index`` and
+    ``cache_layer`` as in ``attention``.
+    """
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    if positions.ndim == 1:
+        positions = jnp.broadcast_to(positions[None, :], (B, S))
+    scale = jnp.float32((nope + rope) ** -0.5)
+    with jax.named_scope("attn.qkv"):
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(B, S, H, nope + rope)
+        q_nope, q_pe = q[..., :nope], q[..., nope:]
+        kv = jnp.einsum("bsd,dc->bsc", x, p["wkv_a"])
+        c = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+        q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+        k_pe = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    w_b = p["wkv_b"].reshape(r, H, nope + vd)
+
+    if cache is None:
+        with jax.named_scope("attn.core"):
+            kv_h = jnp.einsum("bsr,rhk->bshk", c, w_b)
+            k = jnp.concatenate([kv_h[..., :nope], jnp.broadcast_to(
+                k_pe[:, :, None], (B, S, H, rope))], axis=-1)
+            qk = jnp.concatenate([q_nope, q_pe], axis=-1)
+            s = jnp.einsum("bshk,bthk->bhst", qk, k).astype(jnp.float32)
+            s = s * scale
+            causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+            s = jnp.where(causal, s, -1e30)
+            w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            o = jnp.einsum("bhst,bthv->bshv", w, kv_h[..., nope:])
+        new_cache = None
+    else:
+        fill = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (B,))
+        pos = fill[:, None] + jnp.arange(S, dtype=jnp.int32)
+        lead = () if cache_layer is None else (cache_layer,)
+        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+        c_cache, pe_cache = cache
+        with jax.named_scope("attn.kv_cache"):
+            c_cache = _cache_write(c_cache, c, lead, pos)
+            pe_cache = pe_cache.at[(*lead, rows, slice(None), pos)].set(
+                k_pe.astype(pe_cache.dtype), unique_indices=True)
+        with jax.named_scope("attn.core"):
+            c_all, pe_all = c_cache[lead], pe_cache[lead]   # (B,T,r), (B,p,T)
+            q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_b[..., :nope])
+            s = (jnp.einsum("bshr,btr->bhst", q_lat, c_all).astype(
+                jnp.float32) + jnp.einsum("bshp,bpt->bhst", q_pe,
+                                          pe_all).astype(jnp.float32)) * scale
+            kpos = jnp.arange(c_all.shape[1])[None, None, :]
+            qpos = positions[:, :, None]
+            mask = (kpos <= qpos) & (kpos < (fill[:, None, None] + S))
+            s = jnp.where(mask[:, None], s, -1e30)
+            w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            o = jnp.einsum("bhst,btr->bshr", w, c_all)
+        new_cache = (c_cache, pe_cache)
+    with jax.named_scope("attn.out"):
+        if cache is not None:
+            o = jnp.einsum("bshr,rhv->bshv", o, w_b[..., nope:])
+        out = jnp.einsum("bsh,ho->bso", o.reshape(B, S, H * vd), p["wo"])
+    return out, new_cache
+
+
+# ---------------------------------------------------------------------------
 # MLPs
 # ---------------------------------------------------------------------------
 
@@ -322,14 +422,23 @@ def gelu_mlp(p: Params, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def init_moe(key: jax.Array, cfg: ModelConfig) -> Params:
-    ks = jax.random.split(key, 4)
-    E = cfg.n_experts
-    return {
-        "router": dense_init(ks[0], (cfg.d_model, E), jnp.float32),
-        "w_gate": dense_init(ks[1], (E, cfg.d_model, cfg.d_ff), cfg.dtype),
-        "w_up": dense_init(ks[2], (E, cfg.d_model, cfg.d_ff), cfg.dtype),
-        "w_down": dense_init(ks[3], (E, cfg.d_ff, cfg.d_model), cfg.dtype),
+    """A router over all ``n_experts``, the ``experts_held`` experts of
+    this chip's share, and (where the config has them) the shared
+    experts as one SwiGLU and the sigmoid router's correction bias."""
+    ks = jax.random.split(key, 5)
+    E, D, F = cfg.experts_held, cfg.d_model, cfg.expert_ff
+    p = {
+        "router": dense_init(ks[0], (D, cfg.n_experts), jnp.float32),
+        "w_gate": dense_init(ks[1], (E, D, F), cfg.dtype),
+        "w_up": dense_init(ks[2], (E, D, F), cfg.dtype),
+        "w_down": dense_init(ks[3], (E, F, D), cfg.dtype),
     }
+    if cfg.router == "sigmoid":
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    if cfg.n_shared_experts:
+        p["shared"] = init_swiglu(ks[4], dataclasses.replace(
+            cfg, d_ff=cfg.n_shared_experts * F))
+    return p
 
 
 def moe_capacity(cfg: ModelConfig, seq: int) -> int:
@@ -337,28 +446,57 @@ def moe_capacity(cfg: ModelConfig, seq: int) -> int:
     return max(8, -(-c // 8) * 8)  # round up to a multiple of 8
 
 
-def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig
-            ) -> Tuple[jax.Array, jax.Array]:
-    """Top-k capacity-routed MoE.  Returns (output, aux load-balance loss).
-
-    Routing is per-sample (vmapped over batch) via stable argsort ->
-    (E, C) gather, so no (T, E, C) one-hot is ever materialized and the
-    expert dimension shards cleanly over the model axis (EP).
-    """
-    B, S, D = x.shape
+def _route(p: Params, x: jax.Array, cfg: ModelConfig):
+    """(chosen experts, their gates, aux loss) of each token over all
+    ``n_experts``.  softmax: top-k of the probabilities, renormalised.
+    sigmoid: top-k of the scores plus the correction bias, which picks
+    the experts and weighs nothing; gates are the chosen scores,
+    renormalised, times ``routed_scaling``."""
+    B, S, _ = x.shape
     E, K = cfg.n_experts, cfg.top_k
-    C = moe_capacity(cfg, S)
-
     logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)                 # (B,S,E)
-    gate, idx = jax.lax.top_k(probs, K)                     # (B,S,K)
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + p["router_bias"], K)
+        gate = jnp.take_along_axis(scores, idx, axis=-1)
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)             # (B,S,E)
+        gate, idx = jax.lax.top_k(probs, K)                 # (B,S,K)
     gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling != 1.0:
+        gate = gate * cfg.routed_scaling
 
     # aux loss (Switch-style): E * sum_e f_e * p_e
     me = probs.mean(axis=(0, 1))                            # (E,)
     ce = jnp.zeros((E,), jnp.float32).at[idx.reshape(-1)].add(
         jnp.ones((B * S * K,), jnp.float32)) / (B * S * K)
-    aux = E * jnp.sum(me * ce)
+    return idx, gate, E * jnp.sum(me * ce)
+
+
+def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
+            expert_offset: int = 0) -> Tuple[jax.Array, jax.Array]:
+    """Top-k capacity-routed MoE.  Returns (output, aux load-balance loss).
+
+    Routing is over all ``n_experts``; the layer holds ``experts_held``
+    of them, the first being ``expert_offset``, and computes their part
+    of the result only (with ``ep_size`` 1, every expert's).  Tokens
+    routed to experts held elsewhere take no capacity here.  Shared
+    experts, where the config has them, run on every token.  Routing is
+    per-sample (vmapped over batch) via stable argsort -> (E, C) gather,
+    so no (T, E, C) one-hot is ever materialized and the expert
+    dimension shards cleanly over the model axis (EP).
+    """
+    B, S, D = x.shape
+    E, K = cfg.experts_held, cfg.top_k
+    C = moe_capacity(cfg, S)
+
+    with jax.named_scope("moe.route"):
+        idx, gate, aux = _route(p, x, cfg)
+        if E < cfg.n_experts:
+            # another share's expert: bucket E, past this share's own
+            local = idx - expert_offset
+            idx = jnp.where((local >= 0) & (local < E), local, E)
 
     def route_one(xb, idxb, gateb):
         flat_e = idxb.reshape(-1)                           # (S*K,)
@@ -369,9 +507,9 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig
         start = jnp.searchsorted(se, jnp.arange(E, dtype=se.dtype),
                                  side="left")
         slot = jnp.arange(S * K, dtype=jnp.int32) - start[se].astype(jnp.int32)
-        valid = slot < C
+        valid = (slot < C) & (se < E)
         slot = jnp.where(valid, slot, C)
-        buf = se.astype(jnp.int32) * (C + 1) + slot
+        buf = jnp.minimum(se, E - 1).astype(jnp.int32) * (C + 1) + slot
         tok1 = jnp.zeros((E * (C + 1),), jnp.int32).at[buf].set(
             jnp.where(valid, st + 1, 0))
         gbuf = jnp.zeros((E * (C + 1),), jnp.float32).at[buf].set(
@@ -390,5 +528,9 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig
             ye.reshape(-1, D))
         return out[1:]
 
-    y = jax.vmap(route_one)(x, idx, gate)
+    with jax.named_scope("moe.experts"):
+        y = jax.vmap(route_one)(x, idx, gate)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(p["shared"], x)
     return hint(y, "batch", None, None), aux

@@ -3,6 +3,8 @@
 Uniform-layer families (dense, moe, vlm, rwkv) stack per-layer params along
 a leading axis and `lax.scan` over layers with remat — required for the
 64-layer configs to compile fast and keep activation memory at one layer.
+A config with leading dense layers (``n_dense_layers``) has two stacks,
+``dense_layers`` then ``layers``, scanned in turn.
 The hybrid (RecurrentGemma) family scans over its repeating block pattern.
 
 Public entry points (all pure):
@@ -25,7 +27,8 @@ from repro.distributed.hints import hint
 from .common import (ModelConfig, Params, cross_entropy_loss, dense_init,
                      rms_norm, sinusoidal_positions)
 from .layers import (attention, cross_attention, gelu_mlp, init_attention,
-                     init_gelu_mlp, init_moe, init_swiglu, moe_ffn, swiglu)
+                     init_gelu_mlp, init_mla, init_moe, init_swiglu,
+                     mla_attention, moe_ffn, swiglu)
 from .rglru import init_recurrent_block, recurrent_block
 from .rwkv6 import (channel_mix, init_channel_mix, init_time_mix, time_mix)
 
@@ -69,7 +72,10 @@ def _stack(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
-def _init_decoder_layer(key: jax.Array, cfg: ModelConfig) -> Params:
+def _init_decoder_layer(key: jax.Array, cfg: ModelConfig,
+                        dense: bool = False) -> Params:
+    """One layer; ``dense``: a leading layer's SwiGLU in place of the
+    experts."""
     ks = jax.random.split(key, 4)
     p: Params = {"ln1": jnp.zeros((cfg.d_model,), jnp.float32),
                  "ln2": jnp.zeros((cfg.d_model,), jnp.float32)}
@@ -77,8 +83,8 @@ def _init_decoder_layer(key: jax.Array, cfg: ModelConfig) -> Params:
         p["tmix"] = init_time_mix(ks[0], cfg)
         p["cmix"] = init_channel_mix(ks[1], cfg)
         return p
-    p["attn"] = init_attention(ks[0], cfg)
-    if cfg.n_experts:
+    p["attn"] = (init_mla if cfg.kv_lora_rank else init_attention)(ks[0], cfg)
+    if cfg.n_experts and not dense:
         p["moe"] = init_moe(ks[1], cfg)
     else:
         p["mlp"] = init_swiglu(ks[1], cfg)
@@ -119,9 +125,19 @@ def init_model(key: jax.Array, cfg: ModelConfig) -> Params:
             layers.append(p)
         params["layers"] = layers            # heterogeneous: keep as list
         return params
+    nd = 3 + cfg.n_dense_layers
+    if cfg.n_dense_layers:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _init_decoder_layer(k, cfg, dense=True))(ks[3:nd])
     params["layers"] = jax.vmap(lambda k: _init_decoder_layer(k, cfg))(
-        ks[3:])
+        ks[nd:])
     return params
+
+
+def layer_stacks(params: Params):
+    """The stacked layers' groups in the order they run: leading dense
+    layers (where the config has them), then ``layers``."""
+    return [params[k] for k in ("dense_layers", "layers") if k in params]
 
 
 # ---------------------------------------------------------------------------
@@ -148,31 +164,27 @@ def _uniform_layer(cfg: ModelConfig, x, layer_p, window, positions,
         new_cache = {"tmix": tstate, "cmix": cstate} if cache is not None \
             else None
         return x, new_cache, aux
-    if cache is not None and cfg.kv_quant:
-        c_in = (cache["k"], cache["v"], cache["k_scale"], cache["v_scale"])
-    elif cache is not None:
-        c_in = (cache["k"], cache["v"])
+    keys = (("c_kv", "k_pe") if cfg.kv_lora_rank else
+            ("k", "v", "k_scale", "v_scale") if cfg.kv_quant else ("k", "v"))
+    c_in = None if cache is None else tuple(cache[k] for k in keys)
+    if cfg.kv_lora_rank:
+        o, kv = mla_attention(layer_p["attn"], h, cfg, positions, cache=c_in,
+                              cache_index=cache_index,
+                              cache_layer=cache_layer)
     else:
-        c_in = None
-    o, kv = attention(layer_p["attn"], h, cfg, positions, window=window,
-                      cache=c_in, cache_index=cache_index,
-                      cache_layer=cache_layer,
-                      mrope_positions=mrope_positions)
+        o, kv = attention(layer_p["attn"], h, cfg, positions, window=window,
+                          cache=c_in, cache_index=cache_index,
+                          cache_layer=cache_layer,
+                          mrope_positions=mrope_positions)
     x = x + o
     h2 = rms_norm(x, layer_p["ln2"], cfg.norm_eps)
     with jax.named_scope("ffn"):
-        if cfg.n_experts:
+        if "moe" in layer_p:
             o2, aux = moe_ffn(layer_p["moe"], h2, cfg)
         else:
             o2 = swiglu(layer_p["mlp"], h2, cfg)
     x = x + o2
-    if kv is None:
-        new_cache = None
-    elif cfg.kv_quant:
-        new_cache = {"k": kv[0], "v": kv[1], "k_scale": kv[2],
-                     "v_scale": kv[3]}
-    else:
-        new_cache = {"k": kv[0], "v": kv[1]}
+    new_cache = None if kv is None else dict(zip(keys, kv))
     return x, new_cache, aux
 
 
@@ -210,13 +222,24 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             x = layer_fn(x, layer_p)
         aux_total = jnp.zeros((), jnp.float32)
     else:
+        aux_total = jnp.zeros((), jnp.float32)
+        if "dense_layers" in params:
+            def dense_body(carry, lp):
+                x, aux_acc = carry
+                x, _, aux = _uniform_layer(cfg, x, lp, None, positions)
+                return (x, aux_acc + aux), None
+
+            body_fn = jax.checkpoint(dense_body) if remat else dense_body
+            (x, aux_total), _ = jax.lax.scan(
+                body_fn, (x, aux_total), params["dense_layers"])
         # scan over *pattern groups* so each position's attention window is
         # a static int — local layers then slice only the keys they can see
         # (chunked attention) instead of masking an S x S score matrix
         pat = (cfg.global_every
                if (cfg.local_window > 0 and cfg.global_every > 0
                    and cfg.arch_kind != "rwkv") else 1)
-        L = cfg.n_layers
+        L = cfg.n_layers - cfg.n_dense_layers
+        wins = wins[cfg.n_dense_layers:]
         n_groups, rem = divmod(L, pat)
         pat_windows = [None if wins[j] >= BIG_WINDOW else wins[j]
                        for j in range(pat)]
@@ -235,8 +258,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                                  *a.shape[1:]),
             params["layers"])
         body_fn = jax.checkpoint(group_body) if remat else group_body
-        (x, aux_total), _ = jax.lax.scan(
-            body_fn, (x, jnp.zeros((), jnp.float32)), grouped)
+        (x, aux_total), _ = jax.lax.scan(body_fn, (x, aux_total), grouped)
         for l in range(n_groups * pat, L):
             lp = jax.tree.map(lambda a, l=l: a[l], params["layers"])
             win = None if wins[l] >= BIG_WINDOW else wins[l]
@@ -309,6 +331,15 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                                        cfg.rglru_dim), dtype),
                     "h": jnp.zeros((batch, cfg.rglru_dim), jnp.float32)})
         return {"layers": caches}
+    if cfg.kv_lora_rank:
+        if cfg.kv_quant:
+            raise ValueError("kv_quant is not supported with latent attention")
+        # each position's normed latent, and its roped key part stored
+        # position-minor: a 64-wide minor axis would pad to 128 lanes
+        return {"c_kv": jnp.zeros((L, batch, max_len, cfg.kv_lora_rank),
+                                  dtype),
+                "k_pe": jnp.zeros((L, batch, cfg.qk_rope_dim, max_len),
+                                  dtype)}
     # every layer stacked, each position's heads merged into one axis:
     # the form the decode step's scatter, per-layer read and attention
     # all take without a whole-cache relayout (or padding hd to a tile)
@@ -353,7 +384,7 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         positions = index[:, None]
     else:
         positions = jnp.broadcast_to(index, (B, 1)).astype(jnp.int32)
-    windows = layer_windows(cfg)
+    windows = static_layer_windows(cfg)
 
     if cfg.arch_kind == "hybrid":
         new_layers = []
@@ -391,7 +422,9 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         # the stacked cache rides in the carry: each layer scatters its
         # new keys into it and reads its own rows back, so with the cache
         # donated the step updates it in place; scanned as xs/ys, every
-        # layer would be sliced out and a whole new cache stacked
+        # layer would be sliced out and a whole new cache stacked.  The
+        # cache holds every layer of every stack; a stack's layer ids
+        # start where the one before it ended
         def body(carry, scanned):
             x, c = carry
             layer_p, window, l = scanned
@@ -400,9 +433,15 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                      cache_layer=l)
             return (x, c), None
 
-        layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-        (x, new_cache), _ = jax.lax.scan(
-            body, (x, cache), (params["layers"], windows, layer_ids))
+        new_cache, first = cache, 0
+        for stack in layer_stacks(params):
+            n = jax.tree.leaves(stack)[0].shape[0]
+            layer_ids = jnp.arange(first, first + n, dtype=jnp.int32)
+            (x, new_cache), _ = jax.lax.scan(
+                body, (x, new_cache),
+                (stack, jnp.asarray(windows[first:first + n], jnp.int32),
+                 layer_ids))
+            first += n
 
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)

@@ -1,6 +1,8 @@
 """The decode step's KV cache: its stored form, the in-place update
 (donated by ``ServeEngine``, carried through the layer scan, one scatter
-per array a layer), and that it leaves the math unchanged."""
+per array a layer), and that it leaves the math unchanged; for latent
+attention (MLA) the cache is each position's latent and rotary key
+part, read by the absorbed decode."""
 import dataclasses
 
 import jax
@@ -20,13 +22,24 @@ def _config(name):
         return get_config("qwen2.5-3b", smoke=True)
     if name == "moe":
         return get_config("granite-moe-1b-a400m", smoke=True)
+    if name == "mla":
+        # in float32: the absorbed decode and forward's expanded form
+        # round differently in bf16, which flips near-ties of the routing
+        return dataclasses.replace(get_config("moonlight-16b-a3b",
+                                              smoke=True), dtype=jnp.float32)
     # the int8 cache in float32: in bf16 its rounding flips near-ties of
     # a random model's logits, here it leaves the greedy tokens alone
     return dataclasses.replace(get_config("qwen2.5-3b", smoke=True),
                                kv_quant=True, dtype=jnp.float32)
 
 
-CONFIGS = ["dense", "moe", "kv_quant"]
+CONFIGS = ["dense", "moe", "kv_quant", "mla"]
+
+
+def _token_axis(key):
+    """The position axis of a cache array: the rotary key part of a
+    latent cache is stored position-minor."""
+    return 3 if key == "k_pe" else 2
 
 
 @pytest.fixture(scope="module")
@@ -43,6 +56,12 @@ def test_cache_form(name):
     cfg = _config(name)
     L, B, T = cfg.n_layers, 3, 16
     cache = jax.eval_shape(lambda: init_cache(cfg, B, T))
+    if cfg.kv_lora_rank:
+        assert set(cache) == {"c_kv", "k_pe"}
+        assert cache["c_kv"].shape == (L, B, T, cfg.kv_lora_rank)
+        assert cache["k_pe"].shape == (L, B, cfg.qk_rope_dim, T)
+        assert cache["c_kv"].dtype == cache["k_pe"].dtype == cfg.dtype
+        return
     kv = (L, B, T, cfg.n_kv_heads * cfg.hd)
     assert cache["k"].shape == cache["v"].shape == kv
     if cfg.kv_quant:
@@ -65,7 +84,8 @@ def test_step_writes_each_slot_at_its_cursor(models, name):
                          cache, jnp.asarray(cursor))
     for key, a in new.items():
         assert a.shape == cache[key].shape and a.dtype == cache[key].dtype
-        written = np.asarray(jnp.any(a != 0, axis=-1))       # (L, B, T)
+        a = jnp.moveaxis(a, _token_axis(key), -1)
+        written = np.asarray(jnp.any(a != 0, axis=-2))       # (L, B, T)
         want = np.zeros((B, T), bool)
         want[np.arange(B), cursor] = True
         for layer in range(cfg.n_layers):

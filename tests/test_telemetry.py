@@ -22,7 +22,11 @@ TICK_CHILDREN = ["serve.refill", "serve.feed", "serve.dispatch",
                  "serve.sync", "serve.retire"]
 SCOPES = ["attn.qkv", "attn.kv_cache", "attn.core", "attn.out", "ffn",
           "lm_head", "sample"]
-ARCHS = ["qwen2.5-3b", "granite-moe-1b-a400m"]
+ARCHS = ["qwen2.5-3b", "granite-moe-1b-a400m", "moonlight-16b-a3b"]
+#: the expert layer's scopes inside ``ffn``, by configuration
+MOE_SCOPES = {"granite-moe-1b-a400m": ["moe.route", "moe.experts"],
+              "moonlight-16b-a3b": ["moe.route", "moe.experts",
+                                    "moe.shared"]}
 
 
 # -- the ring ---------------------------------------------------------------
@@ -263,10 +267,17 @@ def test_the_step_carries_each_scope(step_hlo, arch, scope):
     assert any(f"/{scope}/" in n for n in names), scope
 
 
+@pytest.mark.parametrize("arch,scope", [(a, s) for a, ss in MOE_SCOPES.items()
+                                        for s in ss])
+def test_the_expert_layer_carries_its_scopes(step_hlo, arch, scope):
+    names = re.findall(r'op_name="([^"]*)"', step_hlo[arch])
+    assert any(f"/ffn/{scope}/" in n for n in names), scope
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_the_scopes_change_only_metadata(step_hlo, arch, monkeypatch):
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     bare = _step_hlo(arch)
-    assert not any(f"/{s}/" in bare for s in SCOPES)
+    assert not any(f"/{s}/" in bare for s in SCOPES + MOE_SCOPES.get(arch, []))
     assert _strip_metadata(bare) == _strip_metadata(step_hlo[arch])

@@ -51,10 +51,12 @@ def _on(sharding, tree):
 
 
 #: the served configurations, at their cells' 32 slots x 2048 positions
-SERVED = ["qwen2.5-3b", "granite-moe-1b-a400m"]
+SERVED = ["qwen2.5-3b", "granite-moe-1b-a400m", "moonlight-16b-a3b"]
 #: while loops in the compiled step: the layer scan, and in the MoE layer
-#: the routing's searchsorted; none runs over the slots
-WHILE_LOOPS = {"qwen2.5-3b": 1, "granite-moe-1b-a400m": 2}
+#: the routing's searchsorted; none runs over the slots (moonlight's
+#: one-layer dense stack compiles to no loop)
+WHILE_LOOPS = {"qwen2.5-3b": 1, "granite-moe-1b-a400m": 2,
+               "moonlight-16b-a3b": 2}
 
 
 @pytest.fixture(scope="module")
@@ -86,6 +88,20 @@ def test_serve_decode_step_fits_one_chip(served_steps):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 6 * 10**9     # the bf16 weights
+    assert total < V5E_HBM_BYTES, mem
+
+
+def test_moonlight_decode_step_fits_one_chip(served_steps):
+    """moonlight-16b-a3b's share of an 8-way expert-parallel layer (all
+    27 layers, 8 of 64 routed experts, both shared, the whole untied
+    vocabulary) with its latent cache at 32 slots of 2048 tokens fits
+    one v5e's 16 GB."""
+    compiled, cache_bytes = served_steps["moonlight-16b-a3b"]
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 6.5e9        # weights and cache
+    assert cache_bytes == 27 * 32 * 2048 * 576 * 2   # latent + rotary key
     assert total < V5E_HBM_BYTES, mem
 
 
