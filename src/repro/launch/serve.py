@@ -50,7 +50,7 @@ from repro.runtime.serve_loop import (AdmissionScheduler, Lane, Request,
 
 
 COUNTERS = ("admitted", "wipes", "prompt_tokens", "decode_tokens",
-            "queue_peak", "spans_dropped")
+            "prefill_chunks", "chunk_tokens", "queue_peak", "spans_dropped")
 
 
 def counter_line(engine: ServeEngine) -> str:

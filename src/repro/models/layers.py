@@ -7,12 +7,14 @@ All layers are einsum-based so GSPMD can shard them; activations follow
 cursor (``cache_index``, shared or per batch row) and write each row's
 new keys and values with one scatter per cache array; the uniform-layer
 decode step carries the whole stacked cache through its layer scan and
-donates it, so the scatter updates it in place.
+donates it, so the scatter updates it in place.  A serve step may carry
+a prompt ``Chunk`` behind its one-token rows: the projections run over
+all the rows at once, and the chunk reads and writes its own slot.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -135,14 +137,94 @@ def _sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 CHUNKED_ATTN_THRESHOLD = 8192
 
 
+class Chunk(NamedTuple):
+    """A prompt chunk riding behind a serve step's one-token rows.
+
+    In a step that carries one, ``x`` is a single row of B + C tokens:
+    first each of the B slots' one token, at its own cursor
+    (``cache_index``, (B,)), then the C chunk tokens, which fill cache
+    row ``slot`` from ``start``, that slot's cursor.  Writes past the
+    cache's end are dropped."""
+    slot: jax.Array     # () int32
+    start: jax.Array    # () int32
+
+
+class _Group(NamedTuple):
+    """Tokens that read and write the cache as one block: tokens ``lo``
+    to ``lo + rows * n`` of the step's flattened (batch, token) axes, as
+    ``rows`` cache rows of ``n`` new tokens each, written from ``fill``
+    (rows,).  ``slot``: the one cache row of a chunk; None: rows 0 to
+    ``rows - 1``."""
+    lo: int
+    rows: int
+    n: int
+    slot: Optional[jax.Array]
+    fill: jax.Array
+
+    def take(self, a: jax.Array) -> jax.Array:
+        """This group's part of a (batch, token, ...) array."""
+        tail = a.shape[2:]
+        if self.lo == 0 and self.rows * self.n == a.shape[0] * a.shape[1]:
+            return a.reshape(self.rows, self.n, *tail)
+        flat = a.reshape(-1, *tail)[self.lo:self.lo + self.rows * self.n]
+        return flat.reshape(self.rows, self.n, *tail)
+
+    def read(self, c: jax.Array, lead: Tuple[jax.Array, ...]) -> jax.Array:
+        """This group's cache rows of one layer: (rows, ...)."""
+        if self.slot is None:
+            return c[lead]
+        return c[(*lead, self.slot)][None]
+
+    def index(self) -> Tuple[jax.Array, jax.Array]:
+        """(cache rows (rows, 1), positions (rows, n)) of its writes."""
+        pos = self.fill[:, None] + jnp.arange(self.n, dtype=jnp.int32)
+        rows = (jnp.arange(self.rows, dtype=jnp.int32)[:, None]
+                if self.slot is None else jnp.reshape(self.slot, (1, 1)))
+        return rows, pos
+
+    def mask(self, positions: jax.Array, T: int,
+             window: Optional[jax.Array] = None) -> jax.Array:
+        """(rows, n, T): causal, nothing past the tokens written, and
+        within ``window`` positions where given."""
+        kpos = jnp.arange(T)[None, None, :]
+        qpos = self.take(positions)[:, :, None]
+        mask = (kpos <= qpos) & (kpos < (self.fill[:, None, None] + self.n))
+        if window is not None:
+            mask = mask & (qpos - kpos < window)
+        return mask
+
+
+def _groups(B: int, S: int, cache_index: jax.Array,
+            chunk: Optional[Chunk]) -> List[_Group]:
+    """The step's token groups: every row, each from its own cursor; or,
+    with a chunk, the one-token rows, then the chunk (last, so that its
+    write wins where its slot's own row writes at the same cursor)."""
+    fill = jnp.asarray(cache_index, jnp.int32)
+    if chunk is None:
+        return [_Group(0, B, S, None, jnp.broadcast_to(fill, (B,)))]
+    n_dec = fill.shape[0]
+    return [_Group(0, n_dec, 1, None, fill),
+            _Group(n_dec, 1, B * S - n_dec, chunk.slot,
+                   jnp.reshape(chunk.start, (1,)))]
+
+
+def _join(outs: List[jax.Array], B: int, S: int) -> jax.Array:
+    """The groups' (rows, n, ...) outputs back as (B, S, ...)."""
+    if len(outs) == 1:
+        return outs[0]
+    flat = jnp.concatenate([o.reshape(-1, *o.shape[2:]) for o in outs])
+    return flat.reshape(B, S, *flat.shape[1:])
+
+
 def _cache_write(c: jax.Array, new: jax.Array, lead: Tuple[jax.Array, ...],
-                 pos: jax.Array) -> jax.Array:
-    """One scatter of ``new`` (B, S, ...) into ``c[*lead]`` at each batch
-    row's positions ``pos`` (B, S); trailing dims take ``c``'s form."""
-    B, S = pos.shape
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-    upd = new.reshape(B, S, *c.shape[len(lead) + 2:]).astype(c.dtype)
-    return c.at[(*lead, rows, pos)].set(upd, unique_indices=True)
+                 at: Tuple[jax.Array, jax.Array]) -> jax.Array:
+    """One scatter of ``new`` (rows, n, ...) into ``c[*lead]`` at ``at``,
+    a group's (rows, positions); trailing dims take ``c``'s form.
+    Positions past the cache's end are dropped."""
+    rows, pos = at
+    upd = new.reshape(*pos.shape, *c.shape[len(lead) + 2:]).astype(c.dtype)
+    return c.at[(*lead, rows, pos)].set(upd, unique_indices=True,
+                                       mode="drop")
 
 
 def attention(p: Params, x: jax.Array, cfg: ModelConfig,
@@ -154,6 +236,7 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
               cache_layer: Optional[jax.Array] = None,
               mrope_positions: Optional[jax.Array] = None,
               rope: bool = True,
+              chunk: Optional[Chunk] = None,
               ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, ...]]]:
     """Self-attention; returns (output, updated cache).
 
@@ -166,7 +249,8 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
     Hkv, hd).  cache_index: first free position — a scalar int32 when
     every batch row fills in lockstep, or a per-row (B,) int32 vector
     when rows advance independently (continuous batching: each decode
-    slot carries its own cursor).
+    slot carries its own cursor).  ``chunk``: a prompt chunk behind
+    per-slot one-token rows (see ``Chunk``).
     """
     B, S, _ = x.shape
     if positions.ndim == 1:
@@ -186,14 +270,13 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
     if cache is not None:
         # each row writes its S new keys from its own cursor; a shared
         # cursor is the same write with every row's cursor equal
-        fill = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (B,))
-        pos = fill[:, None] + jnp.arange(S, dtype=jnp.int32)
+        groups = _groups(B, S, cache_index, chunk)
         lead = () if cache_layer is None else (cache_layer,)
 
-        def read(c, width):
-            """This layer's rows, heads split out: (B, T, Hkv, width)."""
-            c = c[lead]
-            return c.reshape(B, c.shape[1], cfg.n_kv_heads, width)
+        def read(g, c, width):
+            """The group's rows, heads split out: (rows, T, Hkv, width)."""
+            c = g.read(c, lead)
+            return c.reshape(g.rows, c.shape[1], cfg.n_kv_heads, width)
 
         with jax.named_scope("attn.kv_cache"):
             if cfg.kv_quant:
@@ -208,23 +291,27 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
                        jnp.round(v / v_s).astype(jnp.int8), k_s, v_s)
             else:
                 new = (k, v)
-            new_cache = tuple(_cache_write(c, n, lead, pos)
-                              for c, n in zip(cache, new))
+            new_cache = tuple(cache)
+            for g in groups:
+                at = g.index()
+                new_cache = tuple(_cache_write(c, g.take(n), lead, at)
+                                  for c, n in zip(new_cache, new))
         with jax.named_scope("attn.core"):
             hd = q.shape[-1]
             if cfg.kv_quant:
                 ck, cv, ks, vs = new_cache
-                k = read(ck, hd).astype(x.dtype) * read(ks, 1).astype(x.dtype)
-                v = read(cv, hd).astype(x.dtype) * read(vs, 1).astype(x.dtype)
-            else:
-                k, v = (read(c, hd) for c in new_cache)
-            T = k.shape[1]
-            kpos = jnp.arange(T)[None, None, :]            # (1,1,T)
-            qpos = positions[:, :, None]                   # (B,S,1)
-            mask = kpos <= qpos                            # causal vs cache
-            mask = mask & (kpos < (fill[:, None, None] + S))
-            if window is not None:
-                mask = mask & (qpos - kpos < window)
+            outs = []
+            for g in groups:
+                if cfg.kv_quant:
+                    gk = (read(g, ck, hd).astype(x.dtype)
+                          * read(g, ks, 1).astype(x.dtype))
+                    gv = (read(g, cv, hd).astype(x.dtype)
+                          * read(g, vs, 1).astype(x.dtype))
+                else:
+                    gk, gv = (read(g, c, hd) for c in new_cache)
+                mask = g.mask(positions, gk.shape[1], window)
+                outs.append(_sdpa(g.take(q), gk, gv, mask, cfg))
+            out = _join(outs, B, S)
     else:
         new_cache = None
         T = S
@@ -247,9 +334,8 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
         if window is not None:
             mask = mask & (i - j < window)
         mask = jnp.broadcast_to(mask[None], (B, S, T))
-
-    with jax.named_scope("attn.core"):
-        out = _sdpa(q, k, v, mask, cfg)
+        with jax.named_scope("attn.core"):
+            out = _sdpa(q, k, v, mask, cfg)
     with jax.named_scope("attn.out"):
         out = jnp.einsum("bsh,ho->bso", out.reshape(B, S, -1), p["wo"])
     return out, new_cache
@@ -299,6 +385,7 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
                   cache: Optional[Tuple[jax.Array, jax.Array]] = None,
                   cache_index: Optional[jax.Array] = None,
                   cache_layer: Optional[jax.Array] = None,
+                  chunk: Optional[Chunk] = None,
                   ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, ...]]]:
     """Causal latent attention; returns (output, updated cache).
 
@@ -309,8 +396,8 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
     (L, B, qk_rope_dim, T_max), so no axis of the cache is padded to a
     tile; each head's query is taken into latent space through its
     slice of ``wkv_b`` and scores and values are read off the latents,
-    which are never expanded over the cache.  ``cache_index`` and
-    ``cache_layer`` as in ``attention``.
+    which are never expanded over the cache.  ``cache_index``,
+    ``cache_layer`` and ``chunk`` as in ``attention``.
     """
     B, S, _ = x.shape
     H, r = cfg.n_heads, cfg.kv_lora_rank
@@ -341,27 +428,30 @@ def mla_attention(p: Params, x: jax.Array, cfg: ModelConfig,
             o = jnp.einsum("bhst,bthv->bshv", w, kv_h[..., nope:])
         new_cache = None
     else:
-        fill = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (B,))
-        pos = fill[:, None] + jnp.arange(S, dtype=jnp.int32)
+        groups = _groups(B, S, cache_index, chunk)
         lead = () if cache_layer is None else (cache_layer,)
-        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
         c_cache, pe_cache = cache
         with jax.named_scope("attn.kv_cache"):
-            c_cache = _cache_write(c_cache, c, lead, pos)
-            pe_cache = pe_cache.at[(*lead, rows, slice(None), pos)].set(
-                k_pe.astype(pe_cache.dtype), unique_indices=True)
+            for g in groups:
+                rows, pos = at = g.index()
+                c_cache = _cache_write(c_cache, g.take(c), lead, at)
+                pe_cache = pe_cache.at[(*lead, rows, slice(None), pos)].set(
+                    g.take(k_pe).astype(pe_cache.dtype), unique_indices=True,
+                    mode="drop")
         with jax.named_scope("attn.core"):
-            c_all, pe_all = c_cache[lead], pe_cache[lead]   # (B,T,r), (B,p,T)
             q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_b[..., :nope])
-            s = (jnp.einsum("bshr,btr->bhst", q_lat, c_all).astype(
-                jnp.float32) + jnp.einsum("bshp,bpt->bhst", q_pe,
-                                          pe_all).astype(jnp.float32)) * scale
-            kpos = jnp.arange(c_all.shape[1])[None, None, :]
-            qpos = positions[:, :, None]
-            mask = (kpos <= qpos) & (kpos < (fill[:, None, None] + S))
-            s = jnp.where(mask[:, None], s, -1e30)
-            w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-            o = jnp.einsum("bhst,btr->bshr", w, c_all)
+            outs = []
+            for g in groups:
+                c_all, pe_all = g.read(c_cache, lead), g.read(pe_cache, lead)
+                s = (jnp.einsum("bshr,btr->bhst", g.take(q_lat),
+                                c_all).astype(jnp.float32)
+                     + jnp.einsum("bshp,bpt->bhst", g.take(q_pe),
+                                  pe_all).astype(jnp.float32)) * scale
+                mask = g.mask(positions, c_all.shape[1])
+                s = jnp.where(mask[:, None], s, -1e30)
+                w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+                outs.append(jnp.einsum("bhst,btr->bshr", w, c_all))
+            o = _join(outs, B, S)
         new_cache = (c_cache, pe_cache)
     with jax.named_scope("attn.out"):
         if cache is not None:
@@ -475,7 +565,8 @@ def _route(p: Params, x: jax.Array, cfg: ModelConfig):
 
 
 def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
-            expert_offset: int = 0) -> Tuple[jax.Array, jax.Array]:
+            expert_offset: int = 0, capacity: Optional[int] = None
+            ) -> Tuple[jax.Array, jax.Array]:
     """Top-k capacity-routed MoE.  Returns (output, aux load-balance loss).
 
     Routing is over all ``n_experts``; the layer holds ``experts_held``
@@ -485,11 +576,13 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
     experts, where the config has them, run on every token.  Routing is
     per-sample (vmapped over batch) via stable argsort -> (E, C) gather,
     so no (T, E, C) one-hot is ever materialized and the expert
-    dimension shards cleanly over the model axis (EP).
+    dimension shards cleanly over the model axis (EP).  ``capacity``:
+    rows an expert takes from a sample, ``moe_capacity`` where not given;
+    at S a token picks an expert at most once, so none is dropped.
     """
     B, S, D = x.shape
     E, K = cfg.experts_held, cfg.top_k
-    C = moe_capacity(cfg, S)
+    C = capacity or moe_capacity(cfg, S)
 
     with jax.named_scope("moe.route"):
         idx, gate, aux = _route(p, x, cfg)

@@ -13,11 +13,14 @@ Public entry points (all pure):
     loss_fn(params, cfg, batch)              -> scalar loss
     init_cache(cfg, batch, max_len)          -> cache
     decode_step(params, cfg, tokens, cache, index) -> (logits, cache)
+
+``decode_step``'s tokens may be ``ChunkedTokens``: a prompt chunk of
+one slot rides behind the one-token rows (``takes_chunks``).
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,9 +29,9 @@ from repro.distributed.hints import hint
 
 from .common import (ModelConfig, Params, cross_entropy_loss, dense_init,
                      rms_norm, sinusoidal_positions)
-from .layers import (attention, cross_attention, gelu_mlp, init_attention,
-                     init_gelu_mlp, init_mla, init_moe, init_swiglu,
-                     mla_attention, moe_ffn, swiglu)
+from .layers import (Chunk, attention, cross_attention, gelu_mlp,
+                     init_attention, init_gelu_mlp, init_mla, init_moe,
+                     init_swiglu, mla_attention, moe_ffn, swiglu)
 from .rglru import init_recurrent_block, recurrent_block
 from .rwkv6 import (channel_mix, init_channel_mix, init_time_mix, time_mix)
 
@@ -144,12 +147,26 @@ def layer_stacks(params: Params):
 # forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+def _moe_with_chunk(p: Params, x: jax.Array, cfg: ModelConfig, n_dec: int
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """The expert layer over a step's (1, B + C) rows with a prompt
+    chunk: each of the ``n_dec`` one-token rows routed as a sample of
+    its own, as in a plain decode step; the C chunk rows routed together
+    at a capacity of C, so that none is dropped."""
+    D = x.shape[-1]
+    y_dec, _ = moe_ffn(p, x[0, :n_dec, None], cfg)
+    y_chunk, aux = moe_ffn(p, x[:, n_dec:], cfg,
+                           capacity=x.shape[1] - n_dec)
+    return jnp.concatenate([y_dec.reshape(1, n_dec, D), y_chunk], 1), aux
+
+
 def _uniform_layer(cfg: ModelConfig, x, layer_p, window, positions,
                    mrope_positions=None, cache=None, cache_index=None,
-                   cache_layer=None):
+                   cache_layer=None, chunk: Optional[Chunk] = None):
     """One pre-norm decoder layer; returns (x, new_cache, aux).  With
     ``cache_layer`` the cache is every layer's, stacked (see
-    ``attention``), and comes back whole."""
+    ``attention``), and comes back whole.  With ``chunk``, ``x`` is the
+    (1, B + C) rows of a step that carries a prompt chunk (``Chunk``)."""
     aux = jnp.zeros((), jnp.float32)
     x = hint(x, "batch", None, None)
     h = rms_norm(x, layer_p["ln1"], cfg.norm_eps)
@@ -170,16 +187,19 @@ def _uniform_layer(cfg: ModelConfig, x, layer_p, window, positions,
     if cfg.kv_lora_rank:
         o, kv = mla_attention(layer_p["attn"], h, cfg, positions, cache=c_in,
                               cache_index=cache_index,
-                              cache_layer=cache_layer)
+                              cache_layer=cache_layer, chunk=chunk)
     else:
         o, kv = attention(layer_p["attn"], h, cfg, positions, window=window,
                           cache=c_in, cache_index=cache_index,
                           cache_layer=cache_layer,
-                          mrope_positions=mrope_positions)
+                          mrope_positions=mrope_positions, chunk=chunk)
     x = x + o
     h2 = rms_norm(x, layer_p["ln2"], cfg.norm_eps)
     with jax.named_scope("ffn"):
-        if "moe" in layer_p:
+        if "moe" in layer_p and chunk is not None:
+            o2, aux = _moe_with_chunk(layer_p["moe"], h2, cfg,
+                                      cache_index.shape[0])
+        elif "moe" in layer_p:
             o2, aux = moe_ffn(layer_p["moe"], h2, cfg)
         else:
             o2 = swiglu(layer_p["mlp"], h2, cfg)
@@ -371,19 +391,58 @@ def zero_cache_slot(cfg: ModelConfig, cache: Params, slot: int) -> Params:
     return jax.tree.map(clear, cache)
 
 
-def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                cache: Params, index: jax.Array
-                ) -> Tuple[jax.Array, Params]:
-    """One decode step.  tokens: (B, 1); index: the cache fill cursor —
-    scalar int32 when all rows decode in lockstep, or per-slot (B,) int32
-    when a continuous-batching engine advances each slot independently."""
-    B = tokens.shape[0]
-    x = params["embed"][tokens]
+class ChunkedTokens(NamedTuple):
+    """A serve step's tokens with one slot's prompt chunk.
+
+    ``decode`` (B, 1): each slot's token, as a plain step takes them;
+    ``chunk`` (C,): the next prompt tokens of slot ``slot``, the first
+    ``length`` (1 to C) real and the rest padding.  The chunk is written
+    from the slot's cursor, and the slot's row of the step's logits is
+    the chunk's, at its last real token."""
+    decode: jax.Array
+    chunk: jax.Array
+    slot: jax.Array     # () int32
+    length: jax.Array   # () int32
+
+
+def takes_chunks(cfg: ModelConfig) -> bool:
+    """Whether ``decode_step`` takes ``ChunkedTokens``: the families
+    whose cache is written at positions (stacked keys and values, or
+    latents).  A recurrent state (rwkv, hybrid) or the encoder-decoder's
+    cache takes one token a step."""
+    return cfg.arch_kind not in ("rwkv", "hybrid", "encdec")
+
+
+def decode_step(params: Params, cfg: ModelConfig, tokens, cache: Params,
+                index: jax.Array) -> Tuple[jax.Array, Params]:
+    """One decode step.  tokens: (B, 1), or ``ChunkedTokens``; index: the
+    cache fill cursor — scalar int32 when all rows decode in lockstep,
+    or per-slot (B,) int32 when a continuous-batching engine advances
+    each slot independently (always, with a chunk).  Returns (logits
+    (B, 1, V), cache)."""
+    chunk = tokens if isinstance(tokens, ChunkedTokens) else None
     index = jnp.asarray(index, jnp.int32)
-    if index.ndim == 1:
-        positions = index[:, None]
+    if chunk is not None:
+        if not takes_chunks(cfg) or index.ndim != 1:
+            raise ValueError("a prompt chunk needs a positional cache and "
+                             "per-slot cursors")
+        # one row of B + C tokens: every projection reads its weights
+        # once for the slots' tokens and the chunk together
+        tokens = chunk.decode
+        B, C = tokens.shape[0], chunk.chunk.shape[0]
+        start = index[chunk.slot]
+        x = params["embed"][jnp.concatenate([tokens[:, 0], chunk.chunk])][None]
+        positions = jnp.concatenate(
+            [index, start + jnp.arange(C, dtype=jnp.int32)])[None]
+        rows = Chunk(chunk.slot, start)
     else:
-        positions = jnp.broadcast_to(index, (B, 1)).astype(jnp.int32)
+        B = tokens.shape[0]
+        x = params["embed"][tokens]
+        if index.ndim == 1:
+            positions = index[:, None]
+        else:
+            positions = jnp.broadcast_to(index, (B, 1)).astype(jnp.int32)
+        rows = None
     windows = static_layer_windows(cfg)
 
     if cfg.arch_kind == "hybrid":
@@ -430,7 +489,7 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
             layer_p, window, l = scanned
             x, c, _ = _uniform_layer(cfg, x, layer_p, window, positions,
                                      cache=c, cache_index=index,
-                                     cache_layer=l)
+                                     cache_layer=l, chunk=rows)
             return (x, c), None
 
         new_cache, first = cache, 0
@@ -444,12 +503,19 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
             first += n
 
     with jax.named_scope("lm_head"):
+        if chunk is not None:
+            # the slots' rows and the chunk's last real one
+            last = jax.lax.dynamic_index_in_dim(x[0], B + chunk.length - 1)
+            x = jnp.concatenate([x[0, :B], last])[:, None]
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
         else:
             logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"])
-        return _mask_pad_vocab(logits, cfg), new_cache
+        logits = _mask_pad_vocab(logits, cfg)
+        if chunk is not None:
+            logits = logits[:B].at[chunk.slot].set(logits[B])
+        return logits, new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Step factories: train_step (grad-accumulation microbatching, remat,
-AdamW) and serve_step (single-token decode), arch-dispatch included.
+AdamW) and serve_step (one token a slot, and optionally one slot's
+prompt chunk), arch-dispatch included.
 
 These are the functions the launcher jits with explicit in/out shardings;
 everything inside is GSPMD-shardable einsum/scan code.
@@ -95,9 +96,12 @@ def make_prefill_step(cfg: ModelConfig) -> Callable:
 
 
 def make_serve_step(cfg: ModelConfig) -> Callable:
-    """(params, tokens(B,1), cache, index) -> (next_tokens, cache).
+    """(params, tokens, cache, index) -> (next_tokens (B, 1), cache).
 
-    One new token against a seq_len KV cache (greedy argmax sampling).
+    One new token a slot against a seq_len KV cache (greedy argmax
+    sampling).  ``tokens`` is (B, 1), or ``transformer.ChunkedTokens``
+    (a prompt chunk of one slot beside the slots' tokens; that slot's
+    sample is the chunk's); ``index`` the (B,) or shared cursor.
     """
     def serve_step(params, tokens, cache, index):
         if cfg.arch_kind == "encdec":

@@ -195,8 +195,15 @@ def test_counters_agree_with_the_run(dense, name):
     assert st["admitted"] == len(reqs)
     # every slot's first occupant finds it clean; each later one wipes it
     assert st["wipes"] == len(reqs) - min(slots, len(reqs))
-    assert st["prompt_tokens"] + st["decode_tokens"] == live
+    # the tokens written into the cache: all of each prompt but its last
+    # token (an empty one feeds a BOS), and every output but the last
+    written = sum(max(len(r.prompt), 1) - 1 + len(r.output) for r in reqs)
+    assert st["prompt_tokens"] + st["decode_tokens"] == written
     assert st["decode_tokens"] == sum(len(r.output) for r in reqs)
+    # a prompt token goes in a chunk or in its slot's own row
+    assert 0 < st["chunk_tokens"] <= sum(len(r.prompt) for r in reqs)
+    # a live slot-tick fed one token or carried the tick's chunk
+    assert live == written - st["chunk_tokens"] + st["prefill_chunks"]
     assert st["queue_peak"] == len(reqs)
     assert st["spans_dropped"] == telemetry.dropped()
     s = _tick_spans(t0)
@@ -204,6 +211,26 @@ def test_counters_agree_with_the_run(dense, name):
     assert list(s["name"]).count("serve.tick") == st["ticks"]
     line = counter_line(eng)
     assert all(k in line for k in COUNTERS)
+
+
+def test_chunk_counters_give_the_fill_share(dense):
+    """``prefill_chunks`` counts the ticks that carried a chunk and
+    ``chunk_tokens`` the prompt tokens in them."""
+    cfg, params = dense
+    eng = ServeEngine(params, cfg, batch_slots=2, max_len=32, chunk=4)
+    for rid, n in enumerate([0, 1, 4, 9]):
+        eng.submit(Request(rid=rid, prompt=list(range(1, n + 1)),
+                           max_new_tokens=2))
+    eng.run()
+    st = eng.stats()
+    # the 1-token prompt: one chunk.  The 4- and 9-token prompts are
+    # placed on a tick that wipes, which carries no chunk: one token each
+    # in its own row; then the 4-token prompt's chunk of 3, beside the
+    # 9-token prompt's second token; then its chunks of 4 and 3
+    assert (st["prefill_chunks"], st["chunk_tokens"]) == (4, 11)
+    assert st["chunk_tokens"] / (st["prefill_chunks"] * eng.chunk) == 11 / 16
+    line = counter_line(eng)
+    assert "prefill_chunks 4" in line and "chunk_tokens 11" in line
 
 
 @pytest.mark.parametrize("name", sorted(TRAFFIC))

@@ -6,6 +6,7 @@ VMEM, programs that do not fit HBM.  The chip is described inside a
 module-scoped fixture, never at import: only one process at a time may
 load the TPU library, and every test worker imports this file.
 """
+import inspect
 import re
 
 import jax
@@ -18,8 +19,8 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_mlp import fit_block
 from repro.kernels.ops import mlp_block
 from repro.models import init_model
-from repro.models.transformer import init_cache
-from repro.runtime.serve_loop import jit_serve_step
+from repro.models.transformer import ChunkedTokens, init_cache
+from repro.runtime.serve_loop import ServeEngine, jit_serve_step
 
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -59,10 +60,14 @@ WHILE_LOOPS = {"qwen2.5-3b": 1, "granite-moe-1b-a400m": 2,
                "moonlight-16b-a3b": 2}
 
 
-@pytest.fixture(scope="module")
-def served_steps(one_chip):
+#: the engine's default prompt chunk
+CHUNK = inspect.signature(ServeEngine).parameters["chunk"].default
+
+
+def _compile_served(one_chip, chunk: bool):
     """ServeEngine's jitted step for each served configuration, compiled
-    for one v5e, with the bytes of its cache."""
+    for one v5e, with the bytes of its cache: the one-token shape, or
+    with a prompt chunk (``ChunkedTokens``)."""
     out = {}
     for arch in SERVED:
         cfg = get_config(arch)
@@ -70,14 +75,28 @@ def served_steps(one_chip):
             lambda: init_model(jax.random.PRNGKey(0), cfg)))
         cache = _on(one_chip,
                     jax.eval_shape(lambda: init_cache(cfg, 32, 2048)))
-        tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
-        index = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+        tokens = (ChunkedTokens(i32(32, 1), i32(CHUNK), i32(), i32())
+                  if chunk else i32(32, 1))
         compiled = jit_serve_step(cfg).lower(
-            params, tokens, cache, index).compile()
+            params, tokens, cache, i32(32)).compile()
         cache_bytes = sum(a.size * a.dtype.itemsize
                           for a in jax.tree.leaves(cache))
         out[arch] = compiled, cache_bytes
     return out
+
+
+@pytest.fixture(scope="module")
+def served_steps(one_chip):
+    return _compile_served(one_chip, chunk=False)
+
+
+@pytest.fixture(scope="module")
+def chunk_steps(one_chip):
+    return _compile_served(one_chip, chunk=True)
 
 
 def test_serve_decode_step_fits_one_chip(served_steps):
@@ -130,6 +149,26 @@ def test_serve_step_has_no_loop_over_slots(served_steps, arch):
     compiled, _ = served_steps[arch]
     loops = re.findall(r"= \S.* while\(", compiled.as_text())
     assert len(loops) == WHILE_LOOPS[arch], loops
+
+
+def _device_bytes(mem) -> int:
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_chunk_step_fits_one_chip_in_place(chunk_steps, arch):
+    """The step with a prompt chunk of the engine's default size, at 32
+    slots of 2048 tokens, fits one v5e's 16 GB, and updates the donated
+    cache in place: aliased, the outputs the cache and the samples, no
+    temporary near the cache's size."""
+    compiled, cache_bytes = chunk_steps[arch]
+    mem = compiled.memory_analysis()
+    assert CHUNK == 128
+    assert _device_bytes(mem) < V5E_HBM_BYTES, mem
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
+    assert cache_bytes <= mem.output_size_in_bytes < cache_bytes + 2**20
+    assert mem.temp_size_in_bytes < 0.3 * 10**9, mem
 
 
 @pytest.mark.parametrize("tokens", [37, 300])
