@@ -42,6 +42,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.configs.lm_graphs import decode_graph  # noqa: E402
 from repro.core import PAPER_HW, PlanRequest, Topology  # noqa: E402
 from repro.data.pipeline import DataConfig, TokenDataset  # noqa: E402
 from repro.launch.compile_cache import place_compile_cache  # noqa: E402
@@ -50,8 +51,7 @@ from repro.models import init_model  # noqa: E402
 from repro.models.transformer import (decode_step, forward,  # noqa: E402
                                       init_cache, loss_fn)
 from repro.optim.adamw import AdamWConfig  # noqa: E402
-from repro.runtime.serve_loop import (Request, ServeEngine,  # noqa: E402
-                                      decode_graph)
+from repro.runtime.serve_loop import Request, ServeEngine  # noqa: E402
 from repro.runtime.train_loop import TrainLoopConfig, train  # noqa: E402
 
 ARCH = "qwen2.5-3b"

@@ -1,12 +1,14 @@
-"""Serve a small model with batched greedy decoding + int8 KV cache,
-pricing the decode step from a persisted PipeOrgan plan artifact.
+"""Two halves side by side: a PipeOrgan plan of a small model's decode
+step, read from a persisted plan artifact, and that model served with
+batched greedy decoding (optionally over an int8 KV cache).
 
-The offline-plan -> online-serve path: the first run ("warm-up") plans
-the model's decode graph once and files the plan as a ``PlanArtifact``
-in a ``PlanStore`` directory; every later run admits the artifact with
-ZERO planner invocations — asserted below via the facade's cache
-counters — which is how a serving fleet starts hot without paying the
-planner at boot.
+The plan half: the first run ("warm-up") plans the decode graph
+(``configs.lm_graphs.decode_graph``) once and files the plan as a
+``PlanArtifact`` in a ``PlanStore`` directory; every later run admits
+the artifact with ZERO planner invocations — asserted below via the
+facade's cache counters.  The serve half jits ``make_serve_step`` and
+reads nothing of the plan: the serving runtime does not import the
+planner.
 
     PYTHONPATH=src python examples/serve_lm.py --tokens 32 --kv-quant \
         [--plan-store DIR]
@@ -19,9 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.configs.lm_graphs import decode_graph
 from repro.core import PAPER_HW, PlanRequest, PlanStore, Topology, get_planner
 from repro.models import init_cache, init_model
-from repro.runtime.serve_loop import decode_graph
 from repro.runtime.steps import make_serve_step
 
 

@@ -38,9 +38,9 @@ from .noc import (Flow, FlowBatch, Topology, TrafficStats, analyze,
                   pair_flow_batch, segment_flows, union_flow_batch)
 from .pipeline_model import SegmentCost, chain_edges, segment_cost
 from .plan_api import (Constraint, DEFAULT_OBJECTIVE, METRICS, Objective,
-                       PlanAPIDeprecationWarning, PlanRequest, StrategySpec,
-                       Term, cache_registry, get_strategy, graph_fingerprint,
-                       latency_first, min_dram, min_energy, register_cache,
+                       PlanRequest, StrategySpec, Term, cache_registry,
+                       get_strategy, graph_fingerprint, latency_first,
+                       min_dram, min_energy, register_cache,
                        register_strategy, strategy_names, unregister_cache,
                        unregister_strategy)
 from .planner import (PlanResult, SegmentPlan, STRATEGIES, edges_on_path,
@@ -85,7 +85,7 @@ __all__ = [
     "segment_flows", "union_flow_batch",
     "SegmentCost", "chain_edges", "segment_cost",
     "Constraint", "DEFAULT_OBJECTIVE", "METRICS", "Objective",
-    "PlanAPIDeprecationWarning", "PlanRequest", "StrategySpec", "Term",
+    "PlanRequest", "StrategySpec", "Term",
     "cache_registry", "get_strategy", "latency_first", "min_dram",
     "min_energy", "register_cache", "register_strategy", "strategy_names",
     "unregister_cache", "unregister_strategy",

@@ -6,8 +6,7 @@ groups and the pipeline slot DAG).  ``PlanArtifact`` round-trips the
 whole tree through versioned JSON — *field-identical*, so a plan written
 by an offline planning job and loaded by a serving process is
 indistinguishable from the freshly planned object: the simulator replays
-it, ``validate_plan`` bands it, and the serve loop prices tokens with it
-without ever touching the planner.
+it and ``validate_plan`` bands it without ever touching the planner.
 
 ``PlanStore`` is the directory-of-artifacts layer: plans are filed under
 the ``PlanRequest.cache_token()`` (a content hash of the request

@@ -48,15 +48,6 @@ DEFAULT_MAX_BURSTS = 512
 METRICS = ("latency_cycles", "dram_bytes", "energy")
 
 
-class PlanAPIDeprecationWarning(DeprecationWarning):
-    """Raised (as a warning) by the legacy positional planning API.
-
-    A dedicated subclass so CI can escalate *our* deprecations to errors
-    (``-W error::repro.core.plan_api.PlanAPIDeprecationWarning``) without
-    tripping over third-party DeprecationWarnings.
-    """
-
-
 # ---------------------------------------------------------------------------
 # objectives and constraints
 # ---------------------------------------------------------------------------

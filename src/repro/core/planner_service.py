@@ -1,13 +1,13 @@
 """Planner facade: one entry point for all planning, keyed on PlanRequest.
 
-Every call site — benchmarks, examples, the serving loop — plans through a
-``Planner``.  A plan is a pure function of its ``PlanRequest`` (graph
-fingerprint, hardware, topology, strategy, objective, constraints,
-``sim_check``, burst budget), so the facade caches ``PlanResult``s under
-the request itself: repeated planning of the same workload (figure sweeps
-re-planning each task, a serving loop re-admitting the same model) becomes
-a dictionary hit, which is what makes the planner cheap enough to run
-inline rather than only offline.
+Every call site — benchmarks, examples, the multi-tenant serve launcher —
+plans through a ``Planner``.  A plan is a pure function of its
+``PlanRequest`` (graph fingerprint, hardware, topology, strategy,
+objective, constraints, ``sim_check``, burst budget), so the facade
+caches ``PlanResult``s under the request itself: repeated planning of the
+same workload (figure sweeps re-planning each task, a launcher
+re-admitting the same model) becomes a dictionary hit, which is what
+makes the planner cheap enough to run inline rather than only offline.
 
     >>> from repro.core import PlanRequest, Planner, PAPER_HW, Topology
     >>> planner = Planner(maxsize=64)
@@ -18,11 +18,6 @@ inline rather than only offline.
 An attached ``PlanStore`` extends the cache to disk (the offline-plan ->
 online-serve path): an LRU miss first consults the store, so a process
 that inherits pre-planned artifacts never invokes a strategy function.
-
-The legacy positional signature ``plan(graph, hw, topology, strategy,
-sim_check)`` survives as a thin shim that emits
-``PlanAPIDeprecationWarning`` and builds the equivalent request — same
-cache, same results, one release of grace.
 """
 from __future__ import annotations
 
@@ -35,9 +30,8 @@ from typing import Dict, Mapping, Optional, Tuple, Union
 from .artifact import PlanSchemaError, PlanStore, SpanShelf
 from .graph import Graph
 from .hwconfig import HWConfig, PAPER_HW
-from .noc import Topology, flow_batch_cache_info
-from .plan_api import (PlanAPIDeprecationWarning, PlanRequest,
-                       get_strategy, register_cache)
+from .noc import flow_batch_cache_info
+from .plan_api import PlanRequest, get_strategy, register_cache
 from .plan_api import cache_registry as _global_cache_registry
 from . import planner as _planner  # noqa: F401  (registers the built-ins)
 from .planner import PlanResult
@@ -49,12 +43,6 @@ CacheInfo = collections.namedtuple("CacheInfo",
 # the NoC flow-batch cache cannot register itself (noc.py sits below
 # plan_api in the import DAG), so the facade module publishes it
 register_cache("flow_batch", flow_batch_cache_info)
-
-
-def _legacy_warn(what: str, instead: str) -> None:
-    warnings.warn(
-        f"{what} is deprecated; {instead} (see docs/api.md)",
-        PlanAPIDeprecationWarning, stacklevel=3)
 
 
 class Planner:
@@ -94,11 +82,7 @@ class Planner:
         self._store_hits = 0
 
     # -- planning ------------------------------------------------------------
-    def plan(self, request: Union[PlanRequest, Graph],
-             hw: Optional[HWConfig] = None,
-             topology: Optional[Topology] = None,
-             strategy: Optional[str] = None,
-             sim_check: Optional[bool] = None,
+    def plan(self, request: PlanRequest,
              verify: Optional[str] = None) -> PlanResult:
         """Plan one ``PlanRequest`` through the LRU cache (and the
         attached ``PlanStore``, if any).
@@ -111,28 +95,7 @@ class Planner:
         ``None`` defers to the planner-wide default set at construction.
         Only freshly planned or store-loaded results are verified — an
         LRU hit was already checked when it entered the cache.
-
-        Passing a ``Graph`` plus the old positional knobs still works but
-        is deprecated: the shim builds the equivalent request, so legacy
-        and request-style calls share cache entries.
         """
-        if isinstance(request, PlanRequest):
-            if not (hw is None and topology is None and strategy is None
-                    and sim_check is None):
-                raise TypeError("pass either a PlanRequest or the legacy "
-                                "(graph, hw, topology, strategy, sim_check) "
-                                "arguments, not both")
-            return self._plan_request(request, verify=verify)
-        _legacy_warn("Planner.plan(graph, hw, topology, strategy, "
-                     "sim_check)", "pass a PlanRequest")
-        return self._plan_request(PlanRequest(
-            graph=request, hw=hw if hw is not None else PAPER_HW,
-            topology=topology,
-            strategy=strategy if strategy is not None else "pipeorgan",
-            sim_check=bool(sim_check)), verify=verify)
-
-    def _plan_request(self, request: PlanRequest,
-                      verify: Optional[str] = None) -> PlanResult:
         mode = self.verify if verify is None else verify
         if mode not in ("off", "warn", "strict"):
             raise ValueError(f"verify={mode!r}; expected 'off', 'warn' "
@@ -174,55 +137,32 @@ class Planner:
             report.raise_if_errors()
         warnings.warn(f"plan verification found problems:\n"
                       f"{report.summary()}", PlanVerifyWarning,
-                      stacklevel=4)
+                      stacklevel=3)
 
     def plan_all(self, graphs: Mapping[str, Graph],
-                 template: Optional[PlanRequest] = None,
-                 hw: Optional[HWConfig] = None,
-                 topology: Optional[Topology] = None,
-                 strategy: Optional[str] = None,
-                 sim_check: Optional[bool] = None
-                 ) -> Dict[str, PlanResult]:
+                 template: PlanRequest) -> Dict[str, PlanResult]:
         """Plan a workload suite (e.g. ``all_tasks()``) through the cache.
 
         ``template`` is a ``PlanRequest`` whose graph is replaced per
         task — every other knob (objective, constraints, ``sim_check``,
-        burst budget) is honored as-is, which fixes the historical bug of
-        this method silently dropping ``sim_check``.  The legacy keyword
-        form still works (deprecated) and now forwards ``sim_check`` too.
+        burst budget) is honored as-is.
         """
-        if template is not None:
-            if not (hw is None and topology is None and strategy is None
-                    and sim_check is None):
-                raise TypeError("pass either a template PlanRequest or "
-                                "the legacy keywords, not both")
-            return {name: self._plan_request(
-                        dataclasses.replace(template, graph=g))
-                    for name, g in graphs.items()}
-        _legacy_warn("Planner.plan_all(graphs, hw, topology, strategy)",
-                     "pass a template PlanRequest")
-        return {name: self._plan_request(PlanRequest(
-                    graph=g, hw=hw if hw is not None else PAPER_HW,
-                    topology=topology,
-                    strategy=strategy if strategy is not None
-                    else "pipeorgan",
-                    sim_check=bool(sim_check)))
+        return {name: self.plan(dataclasses.replace(template, graph=g))
                 for name, g in graphs.items()}
 
     # -- differential validation ---------------------------------------------
-    def validate(self, target, hw: Optional[HWConfig] = None,
-                 topology: Optional[Topology] = None,
-                 strategy: Optional[str] = None,
+    def validate(self, target: Union[PlanRequest, PlanResult],
+                 hw: Optional[HWConfig] = None,
                  max_bursts: Optional[int] = None) -> ValidationReport:
         """Differential-test a plan against the event-driven simulator.
 
         Accepts a ``PlanRequest`` (planned through the cache, validated
         with the request's hardware and burst budget, and the report
-        cached under the request), a ``PlanResult`` (simulated as-is), or
-        — deprecated — a ``Graph`` plus the legacy knobs.  The report
-        carries the declared error-band contract
-        (``simulator.LATENCY_BAND``) plus per-segment analytical-vs-
-        simulated latency, link-load and congestion verdicts.
+        cached under the request) or a ``PlanResult`` (simulated as-is on
+        ``hw`` with ``max_bursts``).  The report carries the declared
+        error-band contract (``simulator.LATENCY_BAND``) plus per-segment
+        analytical-vs-simulated latency, link-load and congestion
+        verdicts.
         """
         if isinstance(target, PlanRequest):
             # plan identity normalizes max_bursts out under sim_check=False
@@ -233,25 +173,16 @@ class Planner:
                 if vkey in self._validate_cache:
                     self._validate_cache.move_to_end(vkey)
                     return self._validate_cache[vkey]
-            plan = self._plan_request(target)
+            plan = self.plan(target)
             report = validate_plan(plan, request=target)
             with self._lock:
                 self._validate_cache[vkey] = report
                 while len(self._validate_cache) > self.maxsize:
                     self._validate_cache.popitem(last=False)
             return report
-        if isinstance(target, PlanResult):
-            return validate_plan(
-                target, hw if hw is not None else PAPER_HW,
-                max_bursts if max_bursts is not None
-                else DEFAULT_MAX_BURSTS)
-        _legacy_warn("Planner.validate(graph, hw, topology, strategy)",
-                     "pass a PlanRequest")
-        return self.validate(PlanRequest(
-            graph=target, hw=hw if hw is not None else PAPER_HW,
-            topology=topology,
-            strategy=strategy if strategy is not None else "pipeorgan",
-            max_bursts=max_bursts))
+        return validate_plan(
+            target, hw if hw is not None else PAPER_HW,
+            max_bursts if max_bursts is not None else DEFAULT_MAX_BURSTS)
 
     # -- cache management ----------------------------------------------------
     def cache_registry(self) -> Dict[str, object]:

@@ -1,18 +1,13 @@
 """Serving launcher: continuous-batching engine over a slot pool.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b [--smoke] \
-        --requests 6 --max-new 12 [--kv-quant] \
-        [--plan] [--plan-store DIR]
+        --requests 6 --max-new 12 [--kv-quant]
 
 Without ``--smoke`` the architecture's published config is served (for
 qwen2.5-3b: 36 layers, d_model 2048, bf16 weights; one TPU v5e holds
-it).  The compile cache goes where ``launch.compile_cache`` says.
-
-``--plan`` attaches the PipeOrgan accelerator plan for the model's decode
-step (a ``PlanRequest`` through the shared planner facade); with
-``--plan-store`` the plan is admitted from / saved to a directory of
-serialized ``PlanArtifact``s, so a warm store serves with zero planner
-invocations at startup — the offline-plan -> online-serve path.
+it).  The compile cache goes where ``launch.compile_cache`` says.  The
+single-engine path plans nothing: the serving runtime does not import
+the planner.
 
 At the end of a run the engine's counters from ``ServeEngine.stats()``
 are printed, one line per lane: requests admitted, slot wipes, prompt
@@ -20,11 +15,15 @@ and decode slot-ticks, the longest queue at a tick's start, and spans
 the telemetry ring overwrote.
 
 ``--tenants "name:share[:priority],..."`` serves several architectures as
-co-resident tenants on one substrate instead: their decode graphs go
-through ``core.multi_tenant.resolve_multi_tenant`` (spatial column bands
-/ time slices / serialized, under the double guard, with cross-tenant
-link + DRAM interference priced), and an ``AdmissionScheduler`` drives
-one ``ServeEngine`` per tenant in the resolved plan's mode:
+co-resident tenants on one substrate instead: their decode graphs
+(``configs.lm_graphs.decode_graph``) go through
+``core.multi_tenant.resolve_multi_tenant`` (spatial column bands / time
+slices / serialized, under the double guard, with cross-tenant link +
+DRAM interference priced), and an ``AdmissionScheduler`` drives one
+``ServeEngine`` per tenant in the resolved plan's mode.  With
+``--plan-store`` the multi-tenant plan is admitted from / saved to a
+directory of serialized artifacts, so a warm store resolves the tenants
+with zero planner invocations:
 
     PYTHONPATH=src python -m repro.launch.serve --smoke \
         --tenants "qwen2.5-3b:2:1,qwen2.5-3b:1" [--plan-store DIR]
@@ -41,12 +40,12 @@ import time
 import jax
 
 from repro.configs import ARCHS, get_config
+from repro.configs.lm_graphs import decode_graph
 from repro.core import (MultiTenantRequest, PAPER_HW, PlanRequest, PlanStore,
                         TenantSpec, Topology, resolve_multi_tenant)
 from repro.launch.compile_cache import place_compile_cache
 from repro.models import init_model
-from repro.runtime.serve_loop import (AdmissionScheduler, Lane, Request,
-                                      ServeEngine, decode_graph)
+from repro.runtime.serve_loop import AdmissionScheduler, Request, ServeEngine
 
 
 COUNTERS = ("admitted", "wipes", "prompt_tokens", "decode_tokens",
@@ -76,9 +75,10 @@ def parse_tenants(spec: str) -> list:
     return out
 
 
-def serve_tenants(args) -> None:
+def serve_tenants(args) -> dict:
     """The multi-tenant serving path: plan the substrate split, then run
-    one admission-scheduled engine per tenant."""
+    one admission-scheduled engine per tenant; returns each tenant's
+    completed requests."""
     tenants = parse_tenants(args.tenants)
     plan_store = PlanStore(args.plan_store) if args.plan_store else None
 
@@ -135,6 +135,7 @@ def serve_tenants(args) -> None:
         print(f"  {name}: {st[f'{name}.completed']:.0f} done, "
               f"mean finish tick {st.get(f'{name}.mean_finish_tick', 0):.1f}"
               f"; {counter_line(engines[name])}")
+    return done
 
 
 def parser() -> argparse.ArgumentParser:
@@ -147,11 +148,9 @@ def parser() -> argparse.ArgumentParser:
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--kv-quant", action="store_true")
-    ap.add_argument("--plan", action="store_true",
-                    help="attach the accelerator plan for the decode step")
     ap.add_argument("--plan-store", default=None, metavar="DIR",
-                    help="admit/persist the plan as an artifact in DIR "
-                         "(implies --plan)")
+                    help="with --tenants: admit/persist the multi-tenant "
+                         "plan as an artifact in DIR")
     ap.add_argument("--tenants", default=None, metavar="SPEC",
                     help='serve co-resident tenants on one substrate: '
                          '"arch[:share[:priority]],..." (>= 2 entries)')
@@ -169,15 +168,8 @@ def main() -> None:
     if args.kv_quant:
         cfg = dataclasses.replace(cfg, kv_quant=True)
     params = init_model(jax.random.PRNGKey(0), cfg)
-    plan_request = plan_store = None
-    if args.plan or args.plan_store:
-        plan_request = PlanRequest(decode_graph(cfg), hw=PAPER_HW,
-                                   topology=Topology.AMP)
-        if args.plan_store:
-            plan_store = PlanStore(args.plan_store)
     engine = ServeEngine(params, cfg, batch_slots=args.slots,
-                         max_len=args.max_len, plan_request=plan_request,
-                         plan_store=plan_store)
+                         max_len=args.max_len)
     for i in range(args.requests):
         engine.submit(Request(rid=i, prompt=[2 + i, 7, 3 * i + 1],
                               max_new_tokens=args.max_new))
@@ -189,10 +181,6 @@ def main() -> None:
           f"({total/dt:.0f} tok/s, {args.slots} slots, "
           f"kv_quant={cfg.kv_quant})")
     print(f"engine: {counter_line(engine)}")
-    if engine.plan is not None:
-        print(f"decode plan: source={engine.plan_source} "
-              f"{engine.plan.latency_cycles:.3e} cycles/token, "
-              f"{engine.plan.dram_bytes:.3e} DRAM B/token")
     for r in sorted(done, key=lambda r: r.rid)[:3]:
         print(f"  rid={r.rid} out={r.output}")
 

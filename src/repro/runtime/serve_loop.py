@@ -35,41 +35,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (Graph, HWConfig, PlanAPIDeprecationWarning,
-                        PlanRequest, PlanSchemaError, PlanStore, Topology,
-                        gemm, get_planner)
 from repro.models.common import ModelConfig
 from repro.models.transformer import (ChunkedTokens, init_cache,
                                       takes_chunks, zero_cache_slot)
 from repro.runtime import telemetry
 from repro.runtime.steps import make_serve_step
-
-
-def decode_graph(cfg: ModelConfig) -> Graph:
-    """One decode step of the transformer as an operator DAG.
-
-    Per layer: QKV projection, attention output projection, MLP up and
-    down GEMMs (M=1: a single token), then the LM head — the shapes the
-    PipeOrgan planner needs to place the decode step on an accelerator.
-    """
-    hd = cfg.hd
-    ops = []
-    prev = None
-
-    def g_(name: str, n: int, k: int) -> None:
-        nonlocal prev
-        ops.append(gemm(name, 1, n, k,
-                        inputs=(prev,) if prev is not None else ()))
-        prev = name
-
-    for layer in range(cfg.n_layers):
-        g_(f"l{layer}.qkv", hd * (cfg.n_heads + 2 * cfg.n_kv_heads),
-           cfg.d_model)
-        g_(f"l{layer}.attn_out", cfg.d_model, cfg.n_heads * hd)
-        g_(f"l{layer}.mlp_up", cfg.d_ff, cfg.d_model)
-        g_(f"l{layer}.mlp_down", cfg.d_model, cfg.d_ff)
-    g_("lm_head", cfg.vocab, cfg.d_model)
-    return Graph(f"{cfg.name}-decode", ops)
 
 
 def jit_serve_step(cfg: ModelConfig):
@@ -108,11 +78,7 @@ class ServeEngine:
     where the model's cache is a recurrent state."""
 
     def __init__(self, params, cfg: ModelConfig, batch_slots: int,
-                 max_len: int, plan_request: Optional[PlanRequest] = None,
-                 plan_store: Optional[PlanStore] = None,
-                 plan_hw: Optional[HWConfig] = None,
-                 plan_topology: Topology = Topology.AMP,
-                 chunk: int = 128):
+                 max_len: int, chunk: int = 128):
         if chunk < 0:
             raise ValueError(f"chunk must be >= 0, not {chunk}")
         self.params = params
@@ -141,41 +107,6 @@ class ServeEngine:
         self.prefill_chunks = 0
         self.chunk_tokens = 0
         self.queue_peak = 0
-        # optional accelerator plan for this model's decode step.  The
-        # resolution order is the offline-plan -> online-serve path:
-        #   1. a ``plan_store`` artifact matching ``plan_request`` exactly
-        #      (zero planner invocations on a warm store);
-        #   2. the shared ``Planner`` facade (identical engines hit the
-        #      LRU plan cache instead of re-planning), after which the
-        #      plan is saved back to the store for the next process.
-        # ``plan_hw``/``plan_topology`` are the deprecated pre-request
-        # knobs, kept as a shim.
-        if plan_hw is not None:
-            if plan_request is not None:
-                raise TypeError("pass plan_request or the deprecated "
-                                "plan_hw/plan_topology, not both")
-            warnings.warn(
-                "ServeEngine(plan_hw=..., plan_topology=...) is "
-                "deprecated; pass plan_request=PlanRequest(decode_graph("
-                "cfg), hw=..., topology=...) (see docs/api.md)",
-                PlanAPIDeprecationWarning, stacklevel=2)
-            plan_request = PlanRequest(decode_graph(cfg), hw=plan_hw,
-                                       topology=plan_topology)
-        self.plan = None
-        self.plan_source: Optional[str] = None
-        self.plan_request = plan_request
-        if plan_request is not None:
-            if plan_store is not None:
-                try:
-                    self.plan = plan_store.load(plan_request)
-                except PlanSchemaError:
-                    self.plan = None   # stale-schema artifact: re-plan
-                self.plan_source = "store" if self.plan is not None else None
-            if self.plan is None:
-                self.plan = get_planner().plan(plan_request)
-                self.plan_source = "planner"
-                if plan_store is not None:
-                    plan_store.save(plan_request, self.plan)
 
     # -- scheduling ----------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -322,8 +253,7 @@ class ServeEngine:
         return done
 
     def stats(self) -> Dict[str, float]:
-        """Engine counters + (when planned) accelerator-model serving
-        estimates.  ``admitted``: requests placed in a slot; ``wipes``:
+        """Engine counters.  ``admitted``: requests placed in a slot; ``wipes``:
         slot wipes on placement into a used slot; ``prompt_tokens``:
         prompt tokens fed that yielded no output (all of a prompt but its
         last token, whose logits give the first output token);
@@ -333,7 +263,7 @@ class ServeEngine:
         carried (over ``prefill_chunks`` x ``chunk``: the chunks' fill
         share).  ``queue_peak``: the longest queue at the start of a
         tick; ``spans_dropped``: spans the telemetry ring overwrote."""
-        out: Dict[str, float] = {
+        return {
             "ticks": float(self.ticks),
             "queued": float(len(self.queue)),
             "active": float(sum(r is not None for r in self.active)),
@@ -347,12 +277,6 @@ class ServeEngine:
             "queue_peak": float(self.queue_peak),
             "spans_dropped": float(telemetry.dropped()),
         }
-        if self.plan is not None:
-            cyc = self.plan.latency_cycles
-            out["planned_cycles_per_token"] = cyc
-            out["planned_dram_bytes_per_token"] = self.plan.dram_bytes
-            out["planned_cycles_total"] = cyc * self.ticks
-        return out
 
 
 @dataclasses.dataclass
@@ -416,7 +340,9 @@ class AdmissionScheduler:
     def from_plan(cls, plan, engines: Dict[str, ServeEngine]
                   ) -> "AdmissionScheduler":
         """Build the scheduler a resolved ``MultiTenantPlan`` prescribes:
-        one lane per tenant (its share/priority) in the plan's mode."""
+        one lane per tenant (its share/priority) in the plan's mode.  It
+        reads only ``plan.tenants`` and ``plan.mode``, so this module
+        needs no planner import."""
         lanes = [Lane(t.name, engines[t.name], t.share, t.priority)
                  for t in plan.tenants]
         return cls(lanes, mode=plan.mode)

@@ -1,6 +1,6 @@
 """Plan artifacts: lossless round-trip, schema gating, the PlanStore, and
-the offline-plan -> online-serve path (zero planner invocations on a warm
-store)."""
+the Planner's read-through of a store (zero strategy invocations on a
+warm store)."""
 import dataclasses
 import json
 
@@ -139,35 +139,3 @@ def test_planner_reads_through_attached_store(tmp_path):
     assert planner.plan(req) is plan              # now in the LRU
     assert planner.store_hits == 1
     assert "plan_store" in planner.cache_info_all()
-
-
-# ---------------------------------------------------------------------------
-# serve-from-store: zero planner invocations after warm-up
-# ---------------------------------------------------------------------------
-
-
-def test_serve_engine_admits_store_artifact(tmp_path):
-    jax = pytest.importorskip("jax")
-    from repro.configs import get_config
-    from repro.models import init_model
-    from repro.runtime.serve_loop import ServeEngine, decode_graph
-
-    cfg = get_config("qwen2.5-3b", smoke=True)
-    params = init_model(jax.random.PRNGKey(0), cfg)
-    request = PlanRequest(decode_graph(cfg), hw=HW, topology=Topology.AMP)
-    store = PlanStore(tmp_path)
-
-    # warm-up: no artifact yet -> planned via the facade, saved back
-    eng = ServeEngine(params, cfg, batch_slots=1, max_len=32,
-                      plan_request=request, plan_store=store)
-    assert eng.plan_source == "planner"
-    assert len(store) == 1
-
-    # after warm-up: the artifact serves with ZERO planner invocations
-    info_before = get_planner().cache_info()
-    eng2 = ServeEngine(params, cfg, batch_slots=1, max_len=32,
-                       plan_request=request, plan_store=store)
-    assert eng2.plan_source == "store"
-    assert get_planner().cache_info() == info_before   # no hit, no miss
-    assert plan_diffs(eng.plan, eng2.plan) == []
-    assert eng2.stats()["planned_cycles_per_token"] > 0

@@ -205,36 +205,3 @@ def test_graph_fingerprint_tracks_structure():
 
 def test_get_planner_is_shared():
     assert get_planner() is get_planner()
-
-
-# ---------------------------------------------------------------------------
-# serving-loop integration
-# ---------------------------------------------------------------------------
-
-def test_serve_engine_plans_through_facade():
-    jax = pytest.importorskip("jax")
-    from repro.configs import get_config
-    from repro.models import init_model
-    from repro.runtime.serve_loop import Request, ServeEngine, decode_graph
-
-    cfg = get_config("qwen2.5-3b", smoke=True)
-    g = decode_graph(cfg)
-    assert len(g.ops) == 4 * cfg.n_layers + 1
-    params = init_model(jax.random.PRNGKey(0), cfg)
-    request = PlanRequest(g, hw=PAPER_HW, topology=Topology.AMP)
-    eng = ServeEngine(params, cfg, batch_slots=1, max_len=32,
-                      plan_request=request)
-    assert eng.plan is not None
-    assert eng.plan_source == "planner"
-    eng.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=2))
-    done = eng.run()
-    assert len(done) == 1
-    stats = eng.stats()
-    assert stats["planned_cycles_per_token"] > 0
-    assert stats["planned_cycles_total"] == \
-        stats["planned_cycles_per_token"] * stats["ticks"]
-    # an identical engine re-plans via the shared facade cache
-    hits_before = get_planner().cache_info().hits
-    ServeEngine(params, cfg, batch_slots=1, max_len=32,
-                plan_request=request)
-    assert get_planner().cache_info().hits == hits_before + 1

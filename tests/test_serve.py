@@ -268,3 +268,18 @@ def test_serve_cli_builds_published_config_without_smoke():
     cfg = get_config(args.arch, smoke=args.smoke)
     assert (cfg.n_layers, cfg.d_model, cfg.vocab) == (36, 2048, 151936)
     assert parser().parse_args(["--smoke"]).smoke
+
+
+def test_serve_tenants_completes_every_request():
+    """The --tenants path: two smoke tenants resolved by the planner, then
+    served through the admission scheduler to the last request."""
+    from repro.launch.serve import parser, serve_tenants
+
+    args = parser().parse_args(["--smoke", "--tenants",
+                                "qwen2.5-3b:2:1,qwen2.5-3b:1",
+                                "--requests", "2", "--max-new", "4"])
+    done = serve_tenants(args)
+    assert sorted(done) == ["qwen2.5-3b#0", "qwen2.5-3b#1"]
+    for reqs in done.values():
+        assert len(reqs) == 2
+        assert all(r.done and len(r.output) == 4 for r in reqs)
