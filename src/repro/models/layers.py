@@ -7,19 +7,23 @@ All layers are einsum-based so GSPMD can shard them; activations follow
 cursor (``cache_index``, shared or per batch row) and write each row's
 new keys and values with one scatter per cache array; the uniform-layer
 decode step carries the whole stacked cache through its layer scan and
-donates it, so the scatter updates it in place.  A serve step may carry
+donates it, so the scatter updates it in place; on a TPU its one-token
+rows read their layer's rows in place, up to each row's cursor, through
+``kernels/decode_attention``.  A serve step may carry
 a prompt ``Chunk`` behind its one-token rows: the projections run over
 all the rows at once, and the chunk reads and writes its own slot.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed.hints import hint, hint_any
+from repro.kernels.decode_attention import block_t, decode_attention
 
 from .common import (ModelConfig, Params, apply_mrope, apply_rope, dense_init,
                      rms_norm)
@@ -227,6 +231,45 @@ def _cache_write(c: jax.Array, new: jax.Array, lead: Tuple[jax.Array, ...],
                                        mode="drop")
 
 
+def _read_in_place(kernel, einsum) -> jax.Array:
+    """The decode-attention kernel where the step is lowered for a TPU,
+    the einsum path for any other platform."""
+    return jax.lax.platform_dependent(tpu=kernel, default=einsum)
+
+
+def _reads_in_place(g: _Group, c: jax.Array, lead: Tuple[jax.Array, ...],
+                    cfg: ModelConfig) -> bool:
+    """Whether the group's read can be the kernel's: one-token rows over
+    the stacked cache, unquantized, positions in whole blocks, and no
+    mesh for the kernel to be split over."""
+    return (g.n == 1 and g.slot is None and bool(lead) and not cfg.kv_quant
+            and _block(c) is not None
+            and jax.sharding.get_abstract_mesh().size <= 1)
+
+
+def _block(c: jax.Array) -> Optional[int]:
+    """The kernel's block of positions over a stacked cache array."""
+    return block_t(c.shape[2], c.shape[3] * c.dtype.itemsize)
+
+
+def _decode_kernel(g: _Group, q: jax.Array, cache: Tuple[jax.Array, ...],
+                   layer: jax.Array, positions: jax.Array,
+                   window: Optional[jax.Array], interpret: bool = False
+                   ) -> jax.Array:
+    """One-token rows' attention over layer ``layer`` of the stacked cache
+    ``(k, v)`` through the kernel, which reads only the positions the
+    group's mask keeps: (rows, 1, H, hd)."""
+    ck, cv = cache
+    T = ck.shape[2]
+    # the mask as a range: kpos <= qpos, kpos < fill + 1, qpos - kpos < window
+    qpos = g.take(positions)[:, 0]
+    hi = jnp.minimum(jnp.minimum(qpos, g.fill) + 1, T)
+    lo = jnp.zeros_like(qpos) if window is None else qpos - window + 1
+    out = decode_attention(q[:, 0], ck, cv, layer, lo, hi, block=_block(ck),
+                           interpret=interpret)
+    return out[:, None]
+
+
 def attention(p: Params, x: jax.Array, cfg: ModelConfig,
               positions: jax.Array,
               window: Optional[jax.Array] = None,
@@ -302,15 +345,23 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig,
                 ck, cv, ks, vs = new_cache
             outs = []
             for g in groups:
-                if cfg.kv_quant:
-                    gk = (read(g, ck, hd).astype(x.dtype)
-                          * read(g, ks, 1).astype(x.dtype))
-                    gv = (read(g, cv, hd).astype(x.dtype)
-                          * read(g, vs, 1).astype(x.dtype))
+                def einsum(g=g):
+                    if cfg.kv_quant:
+                        gk = (read(g, ck, hd).astype(x.dtype)
+                              * read(g, ks, 1).astype(x.dtype))
+                        gv = (read(g, cv, hd).astype(x.dtype)
+                              * read(g, vs, 1).astype(x.dtype))
+                    else:
+                        gk, gv = (read(g, c, hd) for c in new_cache)
+                    mask = g.mask(positions, gk.shape[1], window)
+                    return _sdpa(g.take(q), gk, gv, mask, cfg)
+
+                if _reads_in_place(g, new_cache[0], lead, cfg):
+                    outs.append(_read_in_place(functools.partial(
+                        _decode_kernel, g, g.take(q), new_cache, lead[0],
+                        positions, window), einsum))
                 else:
-                    gk, gv = (read(g, c, hd) for c in new_cache)
-                mask = g.mask(positions, gk.shape[1], window)
-                outs.append(_sdpa(g.take(q), gk, gv, mask, cfg))
+                    outs.append(einsum())
             out = _join(outs, B, S)
     else:
         new_cache = None

@@ -413,6 +413,13 @@ def takes_chunks(cfg: ModelConfig) -> bool:
     return cfg.arch_kind not in ("rwkv", "hybrid", "encdec")
 
 
+def reads_kv_in_place(cfg: ModelConfig) -> bool:
+    """Whether ``decode_step``'s one-token rows read a stacked,
+    unquantized key-value cache: on a TPU the decode-attention kernel
+    reads it in place, up to each slot's cursor (``layers.attention``)."""
+    return takes_chunks(cfg) and not cfg.kv_lora_rank and not cfg.kv_quant
+
+
 def decode_step(params: Params, cfg: ModelConfig, tokens, cache: Params,
                 index: jax.Array) -> Tuple[jax.Array, Params]:
     """One decode step.  tokens: (B, 1), or ``ChunkedTokens``; index: the

@@ -35,9 +35,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.decode_attention import block_t, rows_read
 from repro.models.common import ModelConfig
 from repro.models.transformer import (ChunkedTokens, init_cache,
-                                      takes_chunks, zero_cache_slot)
+                                      reads_kv_in_place,
+                                      static_layer_windows, takes_chunks,
+                                      zero_cache_slot)
 from repro.runtime import telemetry
 from repro.runtime.steps import make_serve_step
 
@@ -97,6 +100,14 @@ class ServeEngine:
         # wiped before reuse so the next occupant can't attend to them
         self._slot_dirty = np.zeros(batch_slots, bool)
         self._step = jit_serve_step(cfg)
+        # the decode read's block of positions where the kernel reads the
+        # cache in place, up to each slot's cursor (on a TPU); None where
+        # the einsum path reads every layer's rows whole
+        row_bytes = cfg.n_kv_heads * cfg.hd * jnp.dtype(cfg.dtype).itemsize
+        self.read_block = (block_t(max_len, row_bytes)
+                           if jax.default_backend() == "tpu"
+                           and reads_kv_in_place(cfg) else None)
+        self._windows = np.asarray(static_layer_windows(cfg), np.int64)
         self.ticks = 0
         self.truncated = False
         # counters, reported by stats()
@@ -107,6 +118,8 @@ class ServeEngine:
         self.prefill_chunks = 0
         self.chunk_tokens = 0
         self.queue_peak = 0
+        self.kv_rows_read = 0
+        self.kv_rows = 0
 
     # -- scheduling ----------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -193,6 +206,11 @@ class ServeEngine:
                 # let a fresh request attend to the previous occupant's
                 # cache rows)
                 index = jnp.asarray(self.pos, jnp.int32)
+                rows = self._windows.size * self.B * self.max_len
+                self.kv_rows += rows
+                self.kv_rows_read += (rows_read(self.pos, self._windows,
+                                                self.max_len, self.read_block)
+                                      if self.read_block else rows)
                 tokens = jax.device_put(tokens)
             with telemetry.span("serve.dispatch"):
                 nxt, self.cache = self._step(self.params, tokens, self.cache,
@@ -262,7 +280,10 @@ class ServeEngine:
         prompt chunk; ``chunk_tokens``: the real prompt tokens they
         carried (over ``prefill_chunks`` x ``chunk``: the chunks' fill
         share).  ``queue_peak``: the longest queue at the start of a
-        tick; ``spans_dropped``: spans the telemetry ring overwrote."""
+        tick; ``kv_read_share``: the share of the cache's (layer, slot,
+        position) rows that the ticks' one-token reads touched, 1.0 where
+        the einsum path reads them whole; ``spans_dropped``: spans the
+        telemetry ring overwrote."""
         return {
             "ticks": float(self.ticks),
             "queued": float(len(self.queue)),
@@ -275,6 +296,8 @@ class ServeEngine:
             "prefill_chunks": float(self.prefill_chunks),
             "chunk_tokens": float(self.chunk_tokens),
             "queue_peak": float(self.queue_peak),
+            "kv_read_share": (self.kv_rows_read / self.kv_rows
+                              if self.kv_rows else 0.0),
             "spans_dropped": float(telemetry.dropped()),
         }
 

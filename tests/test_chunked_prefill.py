@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.models import forward, init_model
+from repro.kernels import decode_attention
+from repro.models import forward, init_model, layers
 from repro.models.layers import moe_capacity, moe_ffn
 from repro.runtime.serve_loop import Request, ServeEngine
 
@@ -226,6 +227,31 @@ def test_an_expert_chunk_drops_no_token(models):
     assert eng.stats()["prefill_chunks"] == 2
     assert out == _serve(params, cfg, reqs, chunk=0, slots=2, max_len=64)[0]
     assert out[0] == _greedy(params, cfg, *reqs[0], max_len=64)
+
+
+@pytest.mark.parametrize("name", ["dense", "moe"])
+def test_kernel_read_keeps_chunk_parity(models, monkeypatch, name):
+    """With the one-token rows' cache read through the decode-attention
+    kernel (interpret mode, blocks of 4 positions, as on a TPU), the
+    engine with chunks serves the tokens of the one-token engine and of
+    greedy decoding through ``forward``.  The expert model runs in
+    float32: the kernel's float32 scores and the einsum's bfloat16 ones
+    round differently, which flips near-ties of the routing."""
+    monkeypatch.setattr(decode_attention, "BLOCK_T", 4)
+    monkeypatch.setattr(layers, "_read_in_place",
+                        lambda kernel, einsum: kernel(interpret=True))
+    cfg, params = models[name]
+    if cfg.n_experts:
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+        params = init_model(KEY, cfg)
+    rng = np.random.default_rng(2)
+    reqs = [(_prompt(cfg, rng, n), 4 + i % 3)
+            for i, n in enumerate([3, C, 2 * C + 5, 1, 30])]
+    chunked, eng = _serve(params, cfg, reqs, chunk=C, max_len=64)
+    assert eng.stats()["prefill_chunks"] > 0
+    assert chunked == _serve(params, cfg, reqs, chunk=0, max_len=64)[0]
+    for (prompt, n), out in zip(reqs, chunked):
+        assert out == _greedy(params, cfg, prompt, n, max_len=64)
 
 
 @pytest.mark.parametrize("arch", ["rwkv6-1.6b", "recurrentgemma-2b"])

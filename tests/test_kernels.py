@@ -5,12 +5,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import decode_attention as da
 from repro.kernels import ref
+from repro.kernels.decode_attention import rows_read
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_mlp import fused_mlp
 from repro.kernels.ops import mlp_block
 from repro.kernels.rglru_scan import rglru_chunked
 from repro.kernels.rwkv6_scan import wkv6
+from repro.models import layers
+from repro.models.common import ModelConfig
 
 KEY = jax.random.PRNGKey(0)
 
@@ -144,3 +148,47 @@ def test_ops_forced_pallas_matches():
     b = mlp_block(x, wg, wu, wd, use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                atol=2e-4, rtol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# decode attention over the stacked cache
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("window", [None, 20])
+@pytest.mark.parametrize("n_kv,hd,groups", [(2, 128, 8), (8, 64, 2)])
+def test_decode_attention_equals_the_einsum_read(monkeypatch, n_kv, hd,
+                                                 groups, window):
+    """One-token rows over layer 1 of a stacked bf16 cache, in blocks of
+    16 positions, slots filled to 0, 1, bt - 1, bt, bt + 1 and T - 1:
+    the kernel gives the einsum path's output (``_sdpa`` over the
+    layer's rows, masked), with and without a window."""
+    monkeypatch.setattr(da, "BLOCK_T", 16)
+    L, T, bt = 3, 64, 16
+    fill = jnp.asarray([0, 1, bt - 1, bt, bt + 1, T - 1], jnp.int32)
+    B, H = fill.shape[0], n_kv * groups
+    cfg = ModelConfig(name="t", arch_kind="dense", n_layers=L,
+                      d_model=H * hd, n_heads=H, n_kv_heads=n_kv, d_ff=64,
+                      vocab=64, head_dim=hd, dtype=jnp.bfloat16)
+    q = jax.random.normal(_k(40), (B, 1, H, hd), jnp.bfloat16)
+    cache = tuple(jax.random.normal(_k(i), (L, B, T, n_kv * hd),
+                                    jnp.bfloat16) for i in (41, 42))
+    g = layers._Group(0, B, 1, None, fill)
+    positions = fill[:, None]
+    win = None if window is None else jnp.int32(window)
+    out = layers._decode_kernel(g, q, cache, jnp.int32(1), positions, win,
+                                interpret=True)
+    k, v = (c[1].reshape(B, T, n_kv, hd) for c in cache)
+    want = layers._sdpa(q, k, v, g.mask(positions, T, win), cfg)
+    assert out.shape == (B, 1, H, hd) and out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
+def test_rows_read_counts_whole_blocks():
+    """Blocks of 16 over 64 positions: a global layer reads each slot's
+    blocks up to its cursor (1, 1, 2, 4), a layer of window 20 from the
+    block of the window's first position (1, 1, 2, 2)."""
+    cursor = np.array([0, 15, 16, 63])
+    assert rows_read(cursor, np.array([1 << 30]), 64, 16) == 8 * 16
+    assert rows_read(cursor, np.array([1 << 30, 20]), 64, 16) == 14 * 16

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.models import decode_step, forward, init_cache, init_model
+from repro.kernels import decode_attention
+from repro.models import decode_step, forward, init_cache, init_model, layers
 from repro.runtime.serve_loop import Request, ServeEngine
 
 KEY = jax.random.PRNGKey(0)
@@ -117,13 +118,7 @@ def _greedy(params, cfg, prompt, n):
     return out
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_engine_tokens_equal_greedy_forward(models, name):
-    """More requests than slots, so slots are wiped and refilled: each
-    request's tokens are those of greedy decoding it alone through
-    ``forward``.  Sequences stay within 8 tokens, where the MoE layer's
-    prefill capacity drops none."""
-    cfg, params = models[name]
+def _serves_greedy_tokens(cfg, params):
     eng = ServeEngine(params, cfg, batch_slots=2, max_len=16)
     rng = np.random.default_rng(7)
     reqs = [Request(rid=i, prompt=[int(t) for t in rng.integers(
@@ -136,3 +131,47 @@ def test_engine_tokens_equal_greedy_forward(models, name):
     for r in reqs:
         assert done[r.rid] == _greedy(params, cfg, r.prompt,
                                       r.max_new_tokens), r.rid
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_engine_tokens_equal_greedy_forward(models, name):
+    """More requests than slots, so slots are wiped and refilled: each
+    request's tokens are those of greedy decoding it alone through
+    ``forward``.  Sequences stay within 8 tokens, where the MoE layer's
+    prefill capacity drops none."""
+    _serves_greedy_tokens(*models[name])
+
+
+@pytest.fixture
+def kernel_read(monkeypatch):
+    """The one-token rows' cache read through the decode-attention kernel
+    (interpret mode), as on a TPU, in blocks of 4 positions."""
+    monkeypatch.setattr(decode_attention, "BLOCK_T", 4)
+    monkeypatch.setattr(layers, "_read_in_place",
+                        lambda kernel, einsum: kernel(interpret=True))
+
+
+@pytest.mark.parametrize("name", ["dense", "moe"])
+def test_kernel_read_serves_the_greedy_tokens(models, kernel_read, name):
+    """As ``test_engine_tokens_equal_greedy_forward``, with the cache read
+    in place by the kernel: cursors run past the first block."""
+    _serves_greedy_tokens(*models[name])
+
+
+def test_kv_read_share_counts_the_blocks_read(models):
+    """Two slots, one prefilling 17 tokens one a tick and the other idle
+    at cursor 0, over 64 positions in blocks of 16: 17 ticks read 35 of
+    136 blocks a layer.  The einsum path reads every row."""
+    cfg, params = models["dense"]
+
+    def share(read_block):
+        eng = ServeEngine(params, cfg, batch_slots=2, max_len=64, chunk=0)
+        eng.read_block = read_block    # as the engine sets it on a TPU
+        eng.submit(Request(rid=0, prompt=list(range(1, 18)),
+                           max_new_tokens=1))
+        eng.run()
+        assert eng.stats()["ticks"] == 17
+        return eng.stats()["kv_read_share"]
+
+    assert share(16) == 35 / 136
+    assert share(None) == 1.0

@@ -6,6 +6,7 @@ VMEM, programs that do not fit HBM.  The chip is described inside a
 module-scoped fixture, never at import: only one process at a time may
 load the TPU library, and every test worker imports this file.
 """
+import functools
 import inspect
 import re
 
@@ -14,12 +15,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_config
+from repro.configs import ARCHS, get_config
+from repro.kernels.decode_attention import block_t, decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_mlp import fit_block
 from repro.kernels.ops import mlp_block
 from repro.models import init_model
-from repro.models.transformer import ChunkedTokens, init_cache
+from repro.models.transformer import (ChunkedTokens, init_cache,
+                                      reads_kv_in_place)
 from repro.runtime.serve_loop import ServeEngine, jit_serve_step
 
 V5E_HBM_BYTES = 16 * 10**9
@@ -169,6 +172,50 @@ def test_chunk_step_fits_one_chip_in_place(chunk_steps, arch):
     assert mem.alias_size_in_bytes >= cache_bytes, mem
     assert cache_bytes <= mem.output_size_in_bytes < cache_bytes + 2**20
     assert mem.temp_size_in_bytes < 0.3 * 10**9, mem
+
+
+@pytest.mark.parametrize("shape", ["served_steps", "chunk_steps"])
+@pytest.mark.parametrize("arch", SERVED)
+def test_decode_rows_read_the_cache_in_place(request, shape, arch):
+    """In both step shapes the GQA configurations' one-token rows read the
+    stacked cache through the decode-attention kernel, one call in the
+    layer scan, and no layer's (1, 32, 2048, Hkv*hd) block is sliced out
+    or relaid; the latent cache's step holds no such kernel."""
+    compiled, _ = request.getfixturevalue(shape)[arch]
+    hlo = compiled.as_text()
+    kernel = re.findall(
+        r'%decode_attention\S* = .*custom_call_target="tpu_custom_call"', hlo)
+    cfg = get_config(arch)
+    if cfg.kv_lora_rank:
+        assert not kernel
+        return
+    assert len(kernel) == 1
+    width = cfg.n_kv_heads * cfg.hd
+    assert not re.findall(rf"%(\S+) = bf16\[1,32,2048,{width}\]", hlo)
+
+
+#: every configuration whose one-token rows the decode kernel reads
+READS_IN_PLACE = [a for a in sorted(ARCHS) if reads_kv_in_place(get_config(a))]
+
+
+@pytest.mark.parametrize("arch", READS_IN_PLACE)
+def test_decode_attention_compiles_at_each_head_layout(one_chip, arch):
+    """The decode-attention kernel at each GQA configuration's published
+    heads, over 8 slots of 2048 positions, in the block ``block_t`` picks
+    (a multi-head 40 x 128 cache takes 32 positions a block, where 512
+    would not fit VMEM), lowers to a Mosaic kernel."""
+    cfg = get_config(arch)
+    width = cfg.n_kv_heads * cfg.hd
+    block = block_t(2048, width * jnp.dtype(cfg.dtype).itemsize)
+
+    def shape(*dims, dtype=cfg.dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cache = shape(2, 8, 2048, width)
+    compiled = jax.jit(functools.partial(decode_attention, block=block)).lower(
+        shape(8, cfg.n_heads, cfg.hd), cache, cache, shape(dtype=jnp.int32),
+        shape(8, dtype=jnp.int32), shape(8, dtype=jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("tokens", [37, 300])
